@@ -640,84 +640,60 @@ def fleet_rows(streams=4, windows_per_stream=6, repeats=REPEATS,
     return rows
 
 
-# Self-contained child program for one sharded_rows cell: serve the
-# standard stream workload on a mesh over every forced host device and
-# print the measured windows/s as JSON. Runs in a SUBPROCESS because
-# device count is fixed at jax init by XLA_FLAGS.
-_SHARDED_CELL = """
-import json, time
-import numpy as np, jax
-from repro.core import EngineConfig, SNNConfig, init_snn
-from repro.core import events as ev
-from repro.serving import StreamEngine
-from repro.distributed import make_mesh
-
-devices, slots, wps_count, repeats = {devices}, {slots}, {wpstream}, {repeats}
-cfg = SNNConfig(height=32, width=32, time_bins=8, conv1_features=4,
-                conv2_features=8, hidden=32, num_classes=11)
-params = init_snn(jax.random.PRNGKey(0), cfg)
-rng = np.random.default_rng(0)
-windows = {{s: [ev.synthetic_gesture_events(rng, (s + k) % 11,
-                                            mean_events=3000,
-                                            height=32, width=32)
-                for k in range(wps_count)]
-            for s in range(slots)}}
-mesh = make_mesh(devices) if devices else None
-eng = StreamEngine(params, cfg, EngineConfig(
-    max_streams=slots, fuse_fc=True, pipeline_depth=1, mesh=mesh))
-handles = {{s: eng.open(stream_id=s, stateful=True)
-            for s in range(slots)}}
-
-def submit_all():
-    for s in range(slots):
-        for w in windows[s]:
-            handles[s].submit(w)
-
-submit_all()
-eng.run()                               # warm-up: compile
-samples = []
-for _ in range(repeats):
-    submit_all()
-    t0 = time.perf_counter()
-    n = len(eng.run())
-    samples.append(n / (time.perf_counter() - t0))
-print(json.dumps({{"devices": devices,
-                   "windows_per_s": float(np.median(samples))}}))
-"""
-
-
 def sharded_rows(device_counts=(1, 2, 4), slots=8, windows_per_stream=8,
                  repeats=REPEATS, out_json="BENCH_stream.json"):
     """Sharded serving throughput (windows/s) vs device count at B=8.
 
-    Each cell is a fresh subprocess forcing ``device_counts[i]`` host
-    devices (``XLA_FLAGS=--xla_force_host_platform_device_count``) and
-    serving the standard stateful pipelined workload with the slot axis
-    sharded over ``make_mesh(d)``; ``devices=1`` is the baseline mesh.
+    Every cell runs in this process: the standard stateful pipelined
+    workload with the slot axis sharded over ``make_mesh(d,
+    devices=jax.devices()[:d])``, for each ``d`` in ``device_counts``
+    that ``jax.devices()`` can give; ``devices=1`` is the baseline mesh.
+    No child process is started, so the process that holds the chip is
+    the one that measures it. On the CPU, force host devices for the
+    whole process (``XLA_FLAGS=--xla_force_host_platform_device_count=4``)
+    to get the D=2 and D=4 cells.
 
     CAVEAT (recorded in the artifact): forced host devices time-slice
-    ONE physical CPU, so windows/s does not scale with d here -- the
+    ONE physical CPU, so windows/s does not scale with d there -- the
     cell measures the sharded step's overhead (it must stay within tol
     of single-device), while real slot-axis scaling needs real devices.
     The regression gate holds each ``sharded_over_single`` ratio.
     """
-    import subprocess
-    import sys
+    from repro.distributed import make_mesh
 
+    cfg = SNNConfig(height=32, width=32, time_bins=8, conv1_features=4,
+                    conv2_features=8, hidden=32, num_classes=11)
+    params = init_snn(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    windows = {s: [ev.synthetic_gesture_events(rng, (s + k) % 11,
+                                               mean_events=3000,
+                                               height=32, width=32)
+                   for k in range(windows_per_stream)]
+               for s in range(slots)}
+    devices = jax.devices()
+    device_counts = [d for d in device_counts if d <= len(devices)]
     results = {}
     for d in device_counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
-        code = _SHARDED_CELL.format(devices=d, slots=slots,
-                                    wpstream=windows_per_stream,
-                                    repeats=repeats)
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, env=env,
-                             timeout=900)
-        if out.returncode != 0:
-            raise RuntimeError(
-                f"sharded cell d={d} failed:\n{out.stderr[-2000:]}")
-        results[d] = json.loads(out.stdout.strip().splitlines()[-1])
+        eng = StreamEngine(params, cfg, EngineConfig(
+            max_streams=slots, fuse_fc=True, pipeline_depth=1,
+            mesh=make_mesh(d, devices=devices[:d])))
+        handles = {s: eng.open(stream_id=s, stateful=True)
+                   for s in range(slots)}
+
+        def submit_all():
+            for s in range(slots):
+                for w in windows[s]:
+                    handles[s].submit(w)
+
+        submit_all()
+        eng.run()                           # warm-up: compile
+        samples = []
+        for _ in range(repeats):
+            submit_all()
+            t0 = time.perf_counter()
+            n = len(eng.run())
+            samples.append(n / (time.perf_counter() - t0))
+        results[d] = {"windows_per_s": float(np.median(samples))}
 
     base_wps = results[min(device_counts)]["windows_per_s"]
     rows, artifact = [], []
@@ -726,7 +702,7 @@ def sharded_rows(device_counts=(1, 2, 4), slots=8, windows_per_stream=8,
         ratio = wps / base_wps
         rows.append((f"stream_sharded_D{d}", 1e6 / wps,
                      f"wps={wps:.1f};sharded_over_single={ratio:.3f};"
-                     f"forced_host_devices"))
+                     f"platform={devices[0].platform}"))
         artifact.append({"devices": d, "batch_size": slots,
                          "windows_per_stream": windows_per_stream,
                          "windows_per_s": wps,
@@ -738,9 +714,10 @@ def sharded_rows(device_counts=(1, 2, 4), slots=8, windows_per_stream=8,
         except FileNotFoundError:
             doc = {"benchmark": "stream_closed_loop"}
         doc["sharded_rows"] = artifact
-        doc["sharded_caveat"] = (
-            "forced host devices share one physical CPU: windows/s "
-            "measures sharded-step overhead, not slot-axis scaling")
+        if devices[0].platform == "cpu":
+            doc["sharded_caveat"] = (
+                "forced host devices share one physical CPU: windows/s "
+                "measures sharded-step overhead, not slot-axis scaling")
         with open(out_json, "w") as f:
             json.dump(doc, f, indent=2)
     return rows
@@ -863,4 +840,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -181,4 +181,6 @@ def fused_vs_separate(snn_params, tcn_params):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

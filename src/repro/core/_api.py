@@ -139,7 +139,9 @@ class EngineConfig:
         and returns results ``pipeline_depth`` steps late (bitwise
         order/value parity with the synchronous engine).
       * ``fuse_fc`` -- route the event wing's fc1/fc2 through the fused
-        synapse+LIF Pallas kernel.
+        synapse+LIF Pallas kernel. With ``StreamEngine(engines=...)``
+        every caller-built event engine must agree (build it with
+        ``BatchedClosedLoop.from_config`` from the same config).
       * ``window_ms`` -- the control-tick window length for the
         real-time accounting.
       * ``mesh`` -- a :class:`jax.sharding.Mesh` (see

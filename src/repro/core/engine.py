@@ -261,17 +261,16 @@ class FrameTCNEngine:
                 # replicated, each device classifying its own rows
                 # (tcn_apply is row-independent, so shards are bitwise
                 # equal to the full batch).
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 _check_slot_divisible(b, self.mesh, "sharded-engine")
                 ax, _ = _mesh_slot_info(self.mesh)
-                run = shard_map(
+                run = jax.shard_map(
                     run, mesh=self.mesh,
                     in_specs=(P(), P(ax, None, None, None)),
                     out_specs=(P(ax), P(ax, None), P(ax, None),
                                {k: P(ax) for k in
                                 ("conv1", "conv2", "fc1", "fc2")}),
-                    check_rep=False)
+                    check_vma=False)
                 px_sh = NamedSharding(self.mesh, P(ax, None, None, None))
                 pk_sh = NamedSharding(self.mesh, P())
             px_abs = jax.ShapeDtypeStruct((b, h, w, 1), jnp.float32,

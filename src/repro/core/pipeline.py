@@ -123,9 +123,8 @@ def _check_slot_divisible(batch_size: int, mesh, what: str) -> None:
 def _shard_wrap(run: Callable, mesh, state_tree) -> Callable:
     """shard_map ``run`` over the slot axis: batch arrays and the
     slot-major state split on their leading dim, params replicated,
-    every output slot-major. ``check_rep=False``: replicated params are
+    every output slot-major. ``check_vma=False``: replicated params are
     closed over per shard; nothing in the step crosses shards."""
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.sharding import slot_state_pspecs
     from jax.sharding import PartitionSpec as P
     ax, _ = _mesh_slot_info(mesh)
@@ -134,8 +133,8 @@ def _shard_wrap(run: Callable, mesh, state_tree) -> Callable:
     in_specs = (P(), row, row, row, row, row, state_specs)
     out_specs = (P(ax), row, row,
                  {k: P(ax) for k in state_tree}, state_specs)
-    return shard_map(run, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pwm_from_logits(logits: jnp.ndarray, num_channels: int = 4) -> jnp.ndarray:

@@ -139,11 +139,19 @@ def _avg_pool(x: jnp.ndarray, k: int) -> jnp.ndarray:
     ) / float(k * k)
 
 
+# Synaptic currents are computed at full float32 precision. A LIF neuron
+# is a threshold: on a TPU the default precision rounds f32 weights to
+# bf16 in the MXU, and the flipped spikes compound through time and
+# layers (served vs the float32 reference on a TPU v5e: fc2 spike
+# agreement 0.90, 21 of 28 labels; at HIGHEST: identical).
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _conv(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """SAME 3x3 conv, NHWC x HWIO -> NHWC."""
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST,
     )
 
 
@@ -158,10 +166,11 @@ def _currents_fn(params: Params, cfg: SNNConfig):
 
     def i3(s2):   # conv2 spikes -> fc1 currents
         pooled = _avg_pool(s2, 2)
-        return pooled.reshape(pooled.shape[0], -1) @ params["fc1"]["w"]
+        return jnp.matmul(pooled.reshape(pooled.shape[0], -1),
+                          params["fc1"]["w"], precision=HIGHEST)
 
     def i4(s3):   # fc1 spikes -> fc2 currents
-        return s3 @ params["fc2"]["w"]
+        return jnp.matmul(s3, params["fc2"]["w"], precision=HIGHEST)
 
     return i1, i2, i3, i4
 
@@ -205,7 +214,10 @@ def snn_apply(
         ``None`` starts from rest (zero membrane).
 
     Returns:
-      dict with ``out_spikes`` (B, T, num_classes), ``out_membrane``
+      dict with ``spikes`` -- each LIF layer's time-major (T, B, ...)
+      spike train, for layer-by-layer comparison against a reference
+      (unused outputs cost nothing under jit) -- ``out_spikes``
+      (B, T, num_classes), ``out_membrane``
       (B, T, num_classes) in time_serial mode, per-layer mean firing
       rates, ``firing_rates_per_stream`` -- per-layer (B,) rates so
       the batched closed loop can drive the energy model per stream --
@@ -262,10 +274,11 @@ def snn_apply(
                    "v3": v3, "s3": s3, "v4": v4, "s4": s4}
             rates = (rate_b(s1, 0), rate_b(s2, 0),
                      rate_b(s3, 0), rate_b(s4, 0))        # each (B,)
-            return new, (s4, v4, rates)
+            return new, ((s1, s2, s3, s4), v4, rates)
 
-        fin, (out_s, out_v, rates) = jax.lax.scan(step, carry, x)
-        out_spikes = jnp.transpose(out_s, (1, 0, 2))     # (B, T, classes)
+        fin, (trains, out_v, rates) = jax.lax.scan(step, carry, x)
+        s1, s2, s3, s4 = trains                          # each (T, B, ...)
+        out_spikes = jnp.transpose(s4, (1, 0, 2))        # (B, T, classes)
         out_membrane = jnp.transpose(out_v, (1, 0, 2))
         r1, r2, r3, r4 = (r.mean(axis=0) for r in rates)  # (T, B) -> (B,)
         state_out = {name: fin[f"v{i}"]
@@ -314,6 +327,7 @@ def snn_apply(
 
     per_stream = {"conv1": r1, "conv2": r2, "fc1": r3, "fc2": r4}
     return {
+        "spikes": {"conv1": s1, "conv2": s2, "fc1": s3, "fc2": s4},
         "out_spikes": out_spikes,
         "out_membrane": out_membrane,
         "firing_rates": {k: v.mean() for k, v in per_stream.items()},

@@ -30,6 +30,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.snn import HIGHEST
 from repro.core.ternary import pack2bit, ternarize
 from repro.kernels.ternary_matmul import ternary_matmul_pallas
 
@@ -147,7 +148,7 @@ def _ternary_conv(x: jnp.ndarray, layer: Params) -> jnp.ndarray:
     w = layer["q"].astype(x.dtype) * layer["scale"].astype(x.dtype)
     return jax.lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST,
     )
 
 
@@ -163,7 +164,8 @@ def _ternarize_act(x: jnp.ndarray, threshold: float) -> jnp.ndarray:
     return jnp.sign(x) * (jnp.abs(x) > delta).astype(x.dtype)
 
 
-def tcn_apply(packed: Params, frames: jnp.ndarray, cfg: TCNConfig,
+def tcn_apply(packed: Params, frames: jnp.ndarray, cfg: TCNConfig, *,
+              ternary_matmul_fn=ternary_matmul_pallas,
               ) -> Dict[str, jnp.ndarray]:
     """Run the CUTIE TCN on normalized frames.
 
@@ -171,6 +173,9 @@ def tcn_apply(packed: Params, frames: jnp.ndarray, cfg: TCNConfig,
       packed: deployment params from :func:`pack_tcn`.
       frames: (B, H, W, C) float frames in [-1, 1]
         (see :func:`repro.core.frames.normalize_frames`).
+      ternary_matmul_fn: the packed fc1 GEMM, ``f(x, w_packed, scale)``;
+        the Pallas kernel by default, ``kernels.ternary_matmul_ref`` for
+        a plain reference.
 
     Returns:
       dict with ``logits`` (B, num_classes) and ``activity_per_stream`` --
@@ -190,10 +195,10 @@ def tcn_apply(packed: Params, frames: jnp.ndarray, cfg: TCNConfig,
     flat = _avg_pool(s2, 2).reshape(frames.shape[0], -1)
     # fc1 through the Pallas kernel: packed 2-bit weights dequantized in
     # VMEM (interpret mode off-TPU), f32 accumulation.
-    h = ternary_matmul_pallas(flat, packed["fc1"]["packed"],
-                              packed["fc1"]["scale"])
+    h = ternary_matmul_fn(flat, packed["fc1"]["packed"],
+                          packed["fc1"]["scale"])
     s3 = _ternarize_act(h, cfg.act_threshold)
-    logits = s3 @ packed["fc2"]["w"]
+    logits = jnp.matmul(s3, packed["fc2"]["w"], precision=HIGHEST)
     return {
         "logits": logits,
         "activity_per_stream": {

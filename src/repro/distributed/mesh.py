@@ -21,7 +21,6 @@ import math
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
-import numpy as np
 from jax.sharding import Mesh
 
 __all__ = ["make_mesh", "slot_axis"]
@@ -70,15 +69,8 @@ def make_mesh(shape: Union[None, int, Sequence[int]] = None,
             f"need {n} devices, have {len(devices)}; set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} (or more) "
             f"before any jax import")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:   # pre-AxisType jax: plain Mesh is equivalent
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
-    auto = (axis_type.Auto,) * len(axes)
-    try:
-        return jax.make_mesh(shape, axes, axis_types=auto,
-                             devices=devices[:n])
-    except TypeError:  # older make_mesh without devices/axis_types kwarg
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices[:n])
 
 
 def slot_axis(mesh: Mesh) -> str:

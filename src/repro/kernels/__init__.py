@@ -9,9 +9,12 @@
                        insight applied to the rwkv6-7b assigned arch)
   ops.py            -- jit'd differentiable wrappers (public API)
   ref.py            -- pure-jnp oracles (tests assert_allclose against them)
+  backend.py        -- use_interpret: the one rule for compiled vs
+                       interpret mode (interpret exactly when not on TPU)
 
-All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling)
-and validated in interpret mode on CPU.
+All kernels are written for TPU (pl.pallas_call + BlockSpec VMEM tiling),
+validated against their oracles in interpret mode on CPU, and compiled
+for a described TPU v5e at Table II widths by tests/test_tpu_compile.py.
 """
 from repro.kernels.ops import (fc_lif_scan, fc_lif_scan_batched, lif_scan,
                                lif_scan_batched, pack_ternary_weights,

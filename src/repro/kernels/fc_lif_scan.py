@@ -45,15 +45,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lif import LIFParams
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.backend import use_interpret
 
 __all__ = ["fc_lif_scan_pallas", "fc_lif_scan_pallas_batched",
            "choose_fc_blocks"]
 
 LANES = 128
-# Weights + a T-block of spikes in/out + currents + state must fit; the
-# full-model fc1 panel (2048 x 512 f32 = 4 MiB) plus a 16-step block at
-# B=8 uses ~5.5 MiB of the 8 MiB default.
+# Weights + a T-block of spikes in/out + currents (value and scratch) +
+# state must fit; the full-model fc1 panel (2048 x 512 f32 = 4 MiB) plus
+# a 16-step block at B=8 uses ~5.8 MiB of the 8 MiB default.
 _DEF_VMEM_BUDGET = 8 * 1024 * 1024
 
 
@@ -65,10 +65,11 @@ def choose_fc_blocks(
 
     Per (T-chunk, N-tile) step the kernel holds: the (K, block_n) weight
     panel, two f32 state planes (membrane scratch + v0), block_t rows of
-    input spikes (B, K), and block_t rows of output spikes + currents
-    (B, block_n). Shrinks block_n (lane-multiple) before block_t; raises
-    when even a (1, LANES) tile exceeds the budget -- never silently
-    overcommits.
+    input spikes (B, K), and block_t rows of output spikes, of the f32
+    currents the matmul produces, and of the f32 current scratch the time
+    loop reads them from (B, block_n). Shrinks block_n (lane-multiple)
+    before block_t; raises when even a (1, LANES) tile exceeds the
+    budget -- never silently overcommits.
     """
     esize = jnp.dtype(dtype).itemsize
     n_padded = n + ((-n) % LANES)
@@ -76,7 +77,7 @@ def choose_fc_blocks(
     while True:
         w_bytes = 4 * k * block_n
         state_bytes = 2 * 4 * b * block_n
-        per_t = b * (k * esize + block_n * (esize + 4))
+        per_t = b * (k * esize + block_n * (esize + 4 + 4))
         avail = vmem_budget - w_bytes - state_bytes
         if avail >= per_t:
             return int(min(max(avail // per_t, 1), t)), block_n
@@ -90,7 +91,7 @@ def choose_fc_blocks(
             f"{need} bytes")
 
 
-def _kernel(spk_ref, w_ref, v0_ref, out_ref, vfin_ref, v_scr,
+def _kernel(spk_ref, w_ref, v0_ref, out_ref, vfin_ref, v_scr, cur_scr,
             *, alpha: float, v_th: float, t_total: int, block_t: int):
     tc = pl.program_id(1)
     n_tc = pl.num_programs(1)
@@ -101,11 +102,14 @@ def _kernel(spk_ref, w_ref, v0_ref, out_ref, vfin_ref, v_scr,
 
     # Synapse stage: all block_t timesteps' currents in one MXU call.
     # (block_t*B, K) @ (K, block_n) is bitwise the same per output element
-    # as the unfused vmap-over-T matmul (independent K-dots).
+    # as the unfused vmap-over-T matmul (independent K-dots). The result
+    # is staged in VMEM scratch so the time loop reads it through a ref:
+    # Mosaic cannot index an in-register value with the loop counter.
     bt, b, k = spk_ref.shape
-    cur_all = jnp.dot(
+    cur_scr[...] = jnp.dot(
         spk_ref[...].reshape(bt * b, k).astype(jnp.float32),
         w_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,   # see core.snn.HIGHEST
         preferred_element_type=jnp.float32,
     ).reshape(bt, b, -1)
 
@@ -113,7 +117,7 @@ def _kernel(spk_ref, w_ref, v0_ref, out_ref, vfin_ref, v_scr,
         # Global timestep; guards the T padding tail (padded steps must
         # not advance the dynamics).
         in_range = tc * block_t + i < t_total
-        cur = cur_all[i]
+        cur = cur_scr[i]
         live = (v < v_th).astype(jnp.float32)       # reset-to-zero mask
         v_new = alpha * v * live + cur
         s = (v_new >= v_th).astype(out_ref.dtype)
@@ -150,8 +154,7 @@ def fc_lif_scan_pallas(
     Forward-only (no AD rules); use ``repro.kernels.ops.fc_lif_scan`` for
     the differentiable (STBP surrogate) wrapper.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = use_interpret(interpret)
     squeeze = spikes.ndim == 2
     if squeeze:
         spikes = spikes[:, None, :]
@@ -209,8 +212,9 @@ def fc_lif_scan_pallas(
             jax.ShapeDtypeStruct((tt, b, nn), spikes.dtype),
             jax.ShapeDtypeStruct((b, nn), spikes.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((b, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((b, bn), jnp.float32),
+                        pltpu.VMEM((bt, b, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

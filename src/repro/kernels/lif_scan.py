@@ -34,7 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lif import LIFParams
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.backend import use_interpret
 
 __all__ = ["lif_scan_pallas", "lif_scan_pallas_batched", "choose_blocks",
            "LANES"]
@@ -123,8 +123,7 @@ def lif_scan_pallas(
     Forward-only (no AD rules); use ``repro.kernels.ops.lif_scan`` for the
     differentiable (STBP surrogate) wrapper.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = use_interpret(interpret)
     orig_shape = currents.shape
     t = orig_shape[0]
     n = 1
@@ -177,7 +176,7 @@ def lif_scan_pallas(
             jax.ShapeDtypeStruct((rr, LANES), currents.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((br, LANES), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.backend import use_interpret
 
 __all__ = ["ternary_matmul_pallas", "choose_blocks_tmm"]
 
@@ -46,7 +46,7 @@ def choose_blocks_tmm(
     def fits(bm, bn, bk):
         x_b = bm * bk * esize
         w_b = (bk // 4) * bn            # uint8
-        unpack_b = bk * bn * 4          # f32 unpack temp (upper bound)
+        unpack_b = 2 * bk * bn * 4      # int32 fields + f32 weights
         acc_b = bm * bn * 4
         out_b = bm * bn * esize
         return x_b + w_b + unpack_b + acc_b + out_b <= vmem_budget
@@ -69,10 +69,12 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, block_k: int,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    b = w_ref[...]  # (block_k // 4, block_n) uint8
+    # (block_k // 4, block_n) packed bytes, widened to int32: Mosaic has
+    # no vector shifts on 8-bit lanes.
+    b = w_ref[...].astype(jnp.int32)
     # Unpack 4 ternary weights per byte: value (j*4+i, n) lives in bits
     # [2i, 2i+2) of byte (j, n), biased by +1 (see core.ternary.pack2bit).
-    parts = [((b >> (2 * i)) & 0x3).astype(jnp.int8) for i in range(4)]
+    parts = [(b >> (2 * i)) & 0x3 for i in range(4)]
     wq = jnp.stack(parts, axis=1)                      # (bk//4, 4, bn)
     wq = wq.reshape(block_k, b.shape[1])               # (bk, bn)
     w_deq = (wq.astype(jnp.float32) - 1.0).astype(x_ref.dtype)
@@ -80,6 +82,10 @@ def _kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, block_k: int,
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w_deq,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        # Full f32 passes for f32 activations (they are not bf16-exact);
+        # bf16 x bf16 products are exact at the default.
+        precision=(jax.lax.Precision.HIGHEST
+                   if x_ref.dtype == jnp.float32 else None),
         preferred_element_type=jnp.float32,
     )
 
@@ -101,8 +107,7 @@ def ternary_matmul_pallas(
     vmem_budget: int = _DEF_VMEM_BUDGET,
 ) -> jnp.ndarray:
     """out = x @ unpack(w_packed) * scale. See module docstring for layout."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = use_interpret(interpret)
     m, k = x.shape
     kp, n = w_packed.shape
     if kp * 4 != k:
@@ -144,7 +149,7 @@ def ternary_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

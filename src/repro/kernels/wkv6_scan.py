@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
+from repro.kernels.backend import use_interpret
 
 __all__ = ["wkv6_scan_pallas"]
 
@@ -79,8 +79,7 @@ def wkv6_scan_pallas(
     Returns (o (B, T, H, hd), state (B, H, hd, hd) f32). Oracle:
     ``repro.kernels.ref.wkv6_ref`` (per head) / ``wkv6_chunked``.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = use_interpret(interpret)
     b, t, h, hd = r.shape
     bh = b * h
 
@@ -123,7 +122,7 @@ def wkv6_scan_pallas(
         out_shape=[jax.ShapeDtypeStruct((tt, bhp, hd), r.dtype),
                    jax.ShapeDtypeStruct((bhp, hd, hd), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_bh, hd, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kk, vv, lw, ub)

@@ -156,8 +156,6 @@ def _quantized_pspecs(pspecs, params_abs, mesh):
 
 def analyze(compiled) -> dict:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     out = {
         "flops": float(cost.get("flops", 0.0)),

@@ -1061,16 +1061,17 @@ class StreamEngine:
             if params is not None or cfg is not None:
                 raise ValueError("(params, cfg) and engines= are "
                                  "mutually exclusive")
-            if config.fuse_fc:
-                raise ValueError(
-                    "fuse_fc configures the internally-built event "
-                    "engine; with engines= pass "
-                    "BatchedClosedLoop(..., fuse_fc=True) yourself")
             if isinstance(engines, Mapping):
                 engines = list(engines.values())
             elif not isinstance(engines, Sequence):
                 engines = [engines]
             for e in engines:
+                if config.fuse_fc and not getattr(e, "fuse_fc", True):
+                    raise ValueError(
+                        f"EngineConfig.fuse_fc=True but engine "
+                        f"'{e.modality}' was built unfused; build it "
+                        f"with BatchedClosedLoop.from_config(..., config) "
+                        f"or BatchedClosedLoop(..., fuse_fc=True)")
                 if config.duration_us is not None:
                     if e.duration_us is None:
                         e.duration_us = config.duration_us

@@ -1,0 +1,98 @@
+"""The trace reduction, on a short trace recorded on a TPU v5 lite.
+
+``data/`` holds the profiler trace of 32 stateless event heads and 4
+fused event+frame heads served through one ``StreamEngine`` at the
+Table II widths, and the HLO text of the two compiled steps. The trace
+holds no benchmark spans, so the cut to the measured window is tested
+on a window span laid over the middle of its device operations."""
+import gzip
+import os
+import types
+
+import pytest
+
+from bench.lib import cells, harness, stats, trace, work
+
+DATA = os.path.join(os.path.dirname(__file__), "data")
+
+
+def _read(name: str) -> bytes:
+    with gzip.open(os.path.join(DATA, name + ".gz")) as f:
+        return f.read()
+
+
+@pytest.fixture(scope="module")
+def hlo():
+    return {w: _read(f"{w}.hlo.txt").decode() for w in ("event", "frame")}
+
+
+@pytest.fixture(scope="module")
+def data():
+    import jax
+    return jax.profiler.ProfileData.from_serialized_xspace(
+        _read("probe.xplane.pb"))
+
+
+@pytest.fixture(scope="module")
+def summary(data, hlo):
+    return trace.from_data(data, hlo, 1)
+
+
+def test_kernels_are_found_by_their_source_file(hlo):
+    ev = trace.hlo_index(hlo["event"])[1]
+    fr = trace.hlo_index(hlo["frame"])[1]
+    assert sorted(ev.values()) == ["fc_lif_scan", "fc_lif_scan",
+                                   "lif_scan", "lif_scan"]
+    assert list(fr.values()) == ["ternary_matmul"]
+
+
+def test_instruction_names():
+    assert trace.instruction("%fusion.3 = f32[4]{0} fusion(f32[4] %a)") \
+        == "fusion.3"
+    assert trace.instruction("jit_run(123)") == "jit_run(123)"
+
+
+def test_summary_is_cut_to_the_measured_window(monkeypatch, data, hlo,
+                                               summary):
+    whole = summary.chips[0]
+    starts = sorted(o.start for o in whole.ops)
+    lo, hi = starts[len(starts) // 4], starts[3 * len(starts) // 4]
+    monkeypatch.setattr(trace, "host_spans", lambda _: [
+        (lo - 10, hi + 10, "step"), (lo, hi, harness.WINDOW_SPAN)])
+    cut = trace.from_data(data, hlo, 1, harness.WINDOW_SPAN)
+    assert cut.window_s == pytest.approx((hi - lo) / 1e9)
+    inside = [o for o in whole.ops if lo <= o.start < hi]
+    assert 0 < len(inside) == len(cut.chips[0].ops) < len(whole.ops)
+    assert all(lo <= o.start < hi and o.end <= hi for o in cut.chips[0].ops)
+    assert all(lo <= a < hi for a, _, _ in cut.chips[0].modules)
+    assert 0 < cut.busy_s <= cut.window_s
+    assert cut.busy_s < summary.busy_s
+
+
+def test_modules_and_kernels_are_attributed(summary):
+    chip = summary.chips[0]
+    n_event = sum(1 for m in chip.modules if m[2] == "event")
+    n_frame = sum(1 for m in chip.modules if m[2] == "frame")
+    assert n_event >= 1 and n_frame == n_event
+    for kernel in ("lif_scan", "fc_lif_scan"):
+        calls, seconds = summary.kernel_calls(kernel)
+        assert calls == 2 * n_event and seconds > 0
+    assert summary.kernel_calls("ternary_matmul")[0] == n_frame
+    assert summary.module_ms("event") > summary.module_ms("frame") > 0
+    assert 0 < summary.busy_s <= summary.window_s
+
+
+def test_breakdown_is_short_and_named(summary):
+    b = summary.breakdown()
+    assert 0 < len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
+    assert all(isinstance(t, float) and t > 0 for _, t in b["device_ops"])
+    assert b["device_ops"][0][0].startswith("event:")
+
+
+def test_roofline_shares_stay_under_100(summary):
+    cell = cells.cell("scnn_paper_saturated")
+    run = types.SimpleNamespace(trace=summary, config=cell.config,
+                                chips=1, peak=work.peaks("TPU v5 lite"))
+    for kernel in ("lif_scan", "fc_lif_scan"):
+        share = stats.roofline_share(run, kernel)
+        assert 0 < share < 100, (kernel, share)

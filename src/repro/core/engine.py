@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import frames as fr
 from repro.core._api import (EngineConfig, suppress_api_deprecations,
                              warn_deprecated_call)
@@ -238,51 +239,58 @@ class FrameTCNEngine:
         cfg = self.cfg
 
         def run(packed, pixels):
-            out = tcn_apply(packed, fr.normalize_frames(pixels), cfg)
-            logits = out["logits"]
-            return (jnp.argmax(logits, -1), pwm_from_logits(logits),
-                    logits, out["activity_per_stream"])
+            with jax.named_scope("cutie"):
+                out = tcn_apply(packed, fr.normalize_frames(pixels), cfg)
+            with jax.named_scope("readout"):
+                logits = out["logits"]
+                return (jnp.argmax(logits, -1), pwm_from_logits(logits),
+                        logits, out["activity_per_stream"])
 
         return run
 
     def _executable(self, key: Tuple[int, ...]) -> Callable:
         """AOT-compile (once) and return the executable for a shape key,
         ``(batch_size, height, width, duration_us)`` -- compilation is
-        eager so :meth:`warmup` can pull it off the serving path."""
+        eager so :meth:`warmup` can pull it off the serving path. Each
+        miss is traced as a ``compile`` span of value 1."""
         exe = self._exe.get(key)
         if exe is None:
-            b, h, w = int(key[0]), int(key[1]), int(key[2])
-            run = self._build_run()
-
-            px_sh = pk_sh = None
-            if self.mesh is not None:
-                # Dense frames shard the same way as the event wing:
-                # pixels split on the slot axis, packed weights
-                # replicated, each device classifying its own rows
-                # (tcn_apply is row-independent, so shards are bitwise
-                # equal to the full batch).
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                _check_slot_divisible(b, self.mesh, "sharded-engine")
-                ax, _ = _mesh_slot_info(self.mesh)
-                run = jax.shard_map(
-                    run, mesh=self.mesh,
-                    in_specs=(P(), P(ax, None, None, None)),
-                    out_specs=(P(ax), P(ax, None), P(ax, None),
-                               {k: P(ax) for k in
-                                ("conv1", "conv2", "fc1", "fc2")}),
-                    check_vma=False)
-                px_sh = NamedSharding(self.mesh, P(ax, None, None, None))
-                pk_sh = NamedSharding(self.mesh, P())
-            px_abs = jax.ShapeDtypeStruct((b, h, w, 1), jnp.float32,
-                                          sharding=px_sh)
-            pk_abs = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
-                                               jnp.asarray(a).dtype,
-                                               sharding=pk_sh),
-                self.packed)
-            exe = jax.jit(run).lower(pk_abs, px_abs).compile()
-            self._exe[key] = exe
+            with tracing.span("compile", lane=self.modality, value=1):
+                exe = self._exe[key] = self._compile(key)
         return exe
+
+    def _compile(self, key: Tuple[int, ...]) -> Callable:
+        """The executable :meth:`_executable` caches for ``key``."""
+        b, h, w = int(key[0]), int(key[1]), int(key[2])
+        run = self._build_run()
+
+        px_sh = pk_sh = None
+        if self.mesh is not None:
+            # Dense frames shard the same way as the event wing:
+            # pixels split on the slot axis, packed weights
+            # replicated, each device classifying its own rows
+            # (tcn_apply is row-independent, so shards are bitwise
+            # equal to the full batch).
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            _check_slot_divisible(b, self.mesh, "sharded-engine")
+            ax, _ = _mesh_slot_info(self.mesh)
+            run = jax.shard_map(
+                run, mesh=self.mesh,
+                in_specs=(P(), P(ax, None, None, None)),
+                out_specs=(P(ax), P(ax, None), P(ax, None),
+                           {k: P(ax) for k in
+                            ("conv1", "conv2", "fc1", "fc2")}),
+                check_vma=False)
+            px_sh = NamedSharding(self.mesh, P(ax, None, None, None))
+            pk_sh = NamedSharding(self.mesh, P())
+        px_abs = jax.ShapeDtypeStruct((b, h, w, 1), jnp.float32,
+                                      sharding=px_sh)
+        pk_abs = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(jnp.shape(a),
+                                           jnp.asarray(a).dtype,
+                                           sharding=pk_sh),
+            self.packed)
+        return jax.jit(run).lower(pk_abs, px_abs).compile()
 
     def warmup(self, shape_keys) -> None:
         """Precompile executables for ``(batch_size, height, width[,
@@ -365,37 +373,44 @@ class FrameTCNEngine:
         return pending if state is None else (pending, state)
 
     def infer_collect(self, pending) -> List[Optional[ClosedLoopResult]]:
-        """Fetch a dispatched batch's outputs and account each slot."""
+        """Fetch a dispatched batch's outputs (a ``fetch`` span) and
+        account each slot (an ``account`` span whose value is the
+        windows accounted)."""
         batch, preds, pwm, logits, activity = pending
-        preds = np.asarray(preds)
-        pwm = np.asarray(pwm)
-        logits = np.asarray(logits)
-        activity = {k: np.asarray(v) for k, v in activity.items()}
+        with tracing.span("fetch", lane=self.modality):
+            preds = np.asarray(preds)
+            pwm = np.asarray(pwm)
+            logits = np.asarray(logits)
+            activity = {k: np.asarray(v) for k, v in activity.items()}
 
         results: List[Optional[ClosedLoopResult]] = []
-        for b in range(batch.batch_size):
-            if not batch.occupied[b]:
-                results.append(None)
-                continue
-            # CUTIE runs its full dense schedule regardless of content;
-            # per-stream differences surface as switching activity.
-            act = float(np.mean([v[b] for v in activity.values()]))
-            acct = self.model.frame_loop(
-                float(batch.num_pixels[b]), self.total_macs, activity=act)
-            latency = float(acct["total_time_ms"])
-            proc_ms = (acct["stages"]["preprocessing"]["time_ms"]
-                       + acct["stages"]["tcn_inference"]["time_ms"])
-            period_ms = max(self.window_ms, proc_ms)
-            results.append(ClosedLoopResult(
-                label_pred=preds[b:b + 1],
-                pwm=pwm[b:b + 1],
-                latency_ms=latency,
-                energy_mj=float(acct["total_energy_mj"]),
-                breakdown=acct,
-                realtime=latency <= self.window_ms,
-                sustained_rate_hz=1000.0 / period_ms,
-                logits=logits[b:b + 1],
-            ))
+        with tracing.span("account", lane=self.modality,
+                          value=int(batch.occupied.sum())):
+            for b in range(batch.batch_size):
+                if not batch.occupied[b]:
+                    results.append(None)
+                    continue
+                # CUTIE runs its full dense schedule regardless of
+                # content; per-stream differences surface as switching
+                # activity.
+                act = float(np.mean([v[b] for v in activity.values()]))
+                acct = self.model.frame_loop(
+                    float(batch.num_pixels[b]), self.total_macs,
+                    activity=act)
+                latency = float(acct["total_time_ms"])
+                proc_ms = (acct["stages"]["preprocessing"]["time_ms"]
+                           + acct["stages"]["tcn_inference"]["time_ms"])
+                period_ms = max(self.window_ms, proc_ms)
+                results.append(ClosedLoopResult(
+                    label_pred=preds[b:b + 1],
+                    pwm=pwm[b:b + 1],
+                    latency_ms=latency,
+                    energy_mj=float(acct["total_energy_mj"]),
+                    breakdown=acct,
+                    realtime=latency <= self.window_ms,
+                    sustained_rate_hz=1000.0 / period_ms,
+                    logits=logits[b:b + 1],
+                ))
         return results
 
     def export_state(self, state, slot: int):

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import events as ev
 from repro.core._api import (EngineConfig, suppress_api_deprecations,
                              warn_deprecated_call)
@@ -401,16 +402,19 @@ class BatchedClosedLoop:
         cfg, scan, fuse = self.cfg, self._lif_scan_fn, self.fuse_fc
 
         def run(params, x, y, t, p, valid, state):
-            vox = ev.voxelize_batch(
-                x, y, t, p, valid, duration_us=duration_us,
-                time_bins=cfg.time_bins, height=cfg.height,
-                width=cfg.width,
-            )
+            with jax.named_scope("voxelize"):
+                vox = ev.voxelize_batch(
+                    x, y, t, p, valid, duration_us=duration_us,
+                    time_bins=cfg.time_bins, height=cfg.height,
+                    width=cfg.width,
+                )
             out = snn_apply(params, vox, cfg, mode="layer_serial",
                             lif_scan_fn=scan, fuse_fc=fuse, state=state)
-            logits = snn_logits(out, cfg) * 10.0
-            return (jnp.argmax(logits, -1), pwm_from_logits(logits), logits,
-                    out["firing_rates_per_stream"], out["state"])
+            with jax.named_scope("readout"):
+                logits = snn_logits(out, cfg) * 10.0
+                return (jnp.argmax(logits, -1), pwm_from_logits(logits),
+                        logits, out["firing_rates_per_stream"],
+                        out["state"])
 
         return run
 
@@ -420,48 +424,53 @@ class BatchedClosedLoop:
         ``key`` is ``(batch_size, max_events, duration_us)``. Compilation
         happens eagerly here -- not lazily inside jit on first call -- so
         :meth:`warmup` can pull the cost off the serving critical path.
+        Each miss is traced as a ``compile`` span of value 1.
         """
         exe = self._exe.get(key)
         if exe is None:
-            b, n_ev, duration_us = key
-            run = self._build_run(int(duration_us))
-            shard = None
-            if self.mesh is not None:
-                from repro.distributed.sharding import slot_shardings
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                _check_slot_divisible(b, self.mesh, "sharded-engine")
-                run = _shard_wrap(run, self.mesh, self._zero_state_for(b))
-                shard = dict(
-                    params=NamedSharding(self.mesh, P()),
-                    row=NamedSharding(
-                        self.mesh,
-                        P(_mesh_slot_info(self.mesh)[0], None)),
-                    state=slot_shardings(self.mesh,
-                                         self._zero_state_for(b)))
-            row_sh = shard["row"] if shard else None
-            ev_i32 = jax.ShapeDtypeStruct((b, n_ev), jnp.int32,
-                                          sharding=row_sh)
-            ev_bool = jax.ShapeDtypeStruct((b, n_ev), jnp.bool_,
-                                           sharding=row_sh)
-
-            def abstract(tree, sh_tree=None):
-                one = lambda a, s=None: jax.ShapeDtypeStruct(
-                    jnp.shape(a), jnp.asarray(a).dtype, sharding=s)
-                if sh_tree is None:
-                    return jax.tree_util.tree_map(one, tree)
-                return jax.tree_util.tree_map(one, tree, sh_tree)
-
-            params_abs = abstract(
-                self.params,
-                jax.tree_util.tree_map(lambda _: shard["params"],
-                                       self.params) if shard else None)
-            state_abs = abstract(self._zero_state_for(b),
-                                 shard["state"] if shard else None)
-            exe = jax.jit(run).lower(
-                params_abs, ev_i32, ev_i32, ev_i32, ev_i32,
-                ev_bool, state_abs).compile()
-            self._exe[key] = exe
+            with tracing.span("compile", lane=self.modality, value=1):
+                exe = self._exe[key] = self._compile(key)
         return exe
+
+    def _compile(self, key) -> Callable:
+        """The executable :meth:`_executable` caches for ``key``."""
+        b, n_ev, duration_us = key
+        run = self._build_run(int(duration_us))
+        shard = None
+        if self.mesh is not None:
+            from repro.distributed.sharding import slot_shardings
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            _check_slot_divisible(b, self.mesh, "sharded-engine")
+            run = _shard_wrap(run, self.mesh, self._zero_state_for(b))
+            shard = dict(
+                params=NamedSharding(self.mesh, P()),
+                row=NamedSharding(
+                    self.mesh,
+                    P(_mesh_slot_info(self.mesh)[0], None)),
+                state=slot_shardings(self.mesh,
+                                     self._zero_state_for(b)))
+        row_sh = shard["row"] if shard else None
+        ev_i32 = jax.ShapeDtypeStruct((b, n_ev), jnp.int32,
+                                      sharding=row_sh)
+        ev_bool = jax.ShapeDtypeStruct((b, n_ev), jnp.bool_,
+                                       sharding=row_sh)
+
+        def abstract(tree, sh_tree=None):
+            one = lambda a, s=None: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.asarray(a).dtype, sharding=s)
+            if sh_tree is None:
+                return jax.tree_util.tree_map(one, tree)
+            return jax.tree_util.tree_map(one, tree, sh_tree)
+
+        params_abs = abstract(
+            self.params,
+            jax.tree_util.tree_map(lambda _: shard["params"],
+                                   self.params) if shard else None)
+        state_abs = abstract(self._zero_state_for(b),
+                             shard["state"] if shard else None)
+        return jax.jit(run).lower(
+            params_abs, ev_i32, ev_i32, ev_i32, ev_i32,
+            ev_bool, state_abs).compile()
 
     def warmup(self, shape_keys) -> None:
         """Precompile executables for the given shape keys.
@@ -600,41 +609,46 @@ class BatchedClosedLoop:
         """Fetch a dispatched batch's outputs and account each stream.
 
         This is the only point that blocks on the device (the implicit
-        ``np.asarray`` device-to-host copies).
+        ``np.asarray`` device-to-host copies, traced as a ``fetch``
+        span); the per-slot accounting is an ``account`` span whose value
+        is the windows accounted.
         """
         batch, preds, pwm, logits, rates_ps = pending
-        preds = np.asarray(preds)
-        pwm = np.asarray(pwm)
-        logits = np.asarray(logits)
-        rates_ps = {k: np.asarray(v) for k, v in rates_ps.items()}
+        with tracing.span("fetch", lane=self.modality):
+            preds = np.asarray(preds)
+            pwm = np.asarray(pwm)
+            logits = np.asarray(logits)
+            rates_ps = {k: np.asarray(v) for k, v in rates_ps.items()}
 
         results: List[Optional[ClosedLoopResult]] = []
-        for b in range(batch.batch_size):
-            if not batch.occupied[b]:
-                results.append(None)
-                continue
-            # A real-but-quiet window (zero events) is still occupied and
-            # gets a result; only window=None slots yield None.
-            n_ev = int(batch.num_events[b])
-            acct = self._account(
-                n_ev, {k: float(v[b]) for k, v in rates_ps.items()})
-            latency = float(acct["total_time_ms"])
-            # Double-buffered acquisition: the uDMA acquires window N+1
-            # during processing of window N, so the sustained period is
-            # max(window period, preprocessing + inference).
-            proc_ms = (acct["stages"]["preprocessing"]["time_ms"]
-                       + acct["stages"]["snn_inference"]["time_ms"])
-            period_ms = max(self.window_ms, proc_ms)
-            results.append(ClosedLoopResult(
-                label_pred=preds[b:b + 1],
-                pwm=pwm[b:b + 1],
-                latency_ms=latency,
-                energy_mj=float(acct["total_energy_mj"]),
-                breakdown=acct,
-                realtime=latency <= self.window_ms,
-                sustained_rate_hz=1000.0 / period_ms,
-                logits=logits[b:b + 1],
-            ))
+        with tracing.span("account", lane=self.modality,
+                          value=int(batch.occupied.sum())):
+            for b in range(batch.batch_size):
+                if not batch.occupied[b]:
+                    results.append(None)
+                    continue
+                # A real-but-quiet window (zero events) is still occupied
+                # and gets a result; only window=None slots yield None.
+                n_ev = int(batch.num_events[b])
+                acct = self._account(
+                    n_ev, {k: float(v[b]) for k, v in rates_ps.items()})
+                latency = float(acct["total_time_ms"])
+                # Double-buffered acquisition: the uDMA acquires window
+                # N+1 during processing of window N, so the sustained
+                # period is max(window period, preprocessing + inference).
+                proc_ms = (acct["stages"]["preprocessing"]["time_ms"]
+                           + acct["stages"]["snn_inference"]["time_ms"])
+                period_ms = max(self.window_ms, proc_ms)
+                results.append(ClosedLoopResult(
+                    label_pred=preds[b:b + 1],
+                    pwm=pwm[b:b + 1],
+                    latency_ms=latency,
+                    energy_mj=float(acct["total_energy_mj"]),
+                    breakdown=acct,
+                    realtime=latency <= self.window_ms,
+                    sustained_rate_hz=1000.0 / period_ms,
+                    logits=logits[b:b + 1],
+                ))
         return results
 
     def export_state(self, state, slot: int):

@@ -290,11 +290,14 @@ def snn_apply(
         run_scan = (lambda cur, v0: scan(cur, lif) if v0 is None
                     else scan(cur, lif, v0))
         v0 = lambda name: None if state is None else state[name]
-        # Layer 2: conv1 + LIF over the full train.
-        c1 = jax.vmap(i1)(x)                  # (T, B, h0, w0, f1)
-        s1, vf1 = run_scan(c1, v0("conv1"))
-        c2 = jax.vmap(i2)(s1)
-        s2, vf2 = run_scan(c2, v0("conv2"))
+        # Layer 2: conv1 + LIF over the full train. Each layer is a
+        # named scope, so a profile of the compiled step names it.
+        with jax.named_scope("conv1"):
+            c1 = jax.vmap(i1)(x)              # (T, B, h0, w0, f1)
+            s1, vf1 = run_scan(c1, v0("conv1"))
+        with jax.named_scope("conv2"):
+            c2 = jax.vmap(i2)(s1)
+            s2, vf2 = run_scan(c2, v0("conv2"))
         if fuse_fc:
             fc_scan = fc_lif_scan_fn
             if fc_scan is None:
@@ -309,14 +312,18 @@ def snn_apply(
                 pooled = _avg_pool(s_t, 2)
                 return pooled.reshape(pooled.shape[0], -1)
 
-            z = jax.vmap(pool_flat)(s2)       # (T, B, flat_dim)
-            s3, vf3 = run_fc(z, params["fc1"]["w"], v0("fc1"))
-            s4, vf4 = run_fc(s3, params["fc2"]["w"], v0("fc2"))
+            with jax.named_scope("fc1"):
+                z = jax.vmap(pool_flat)(s2)   # (T, B, flat_dim)
+                s3, vf3 = run_fc(z, params["fc1"]["w"], v0("fc1"))
+            with jax.named_scope("fc2"):
+                s4, vf4 = run_fc(s3, params["fc2"]["w"], v0("fc2"))
         else:
-            c3 = jax.vmap(i3)(s2)
-            s3, vf3 = run_scan(c3, v0("fc1"))
-            c4 = jax.vmap(i4)(s3)
-            s4, vf4 = run_scan(c4, v0("fc2"))
+            with jax.named_scope("fc1"):
+                c3 = jax.vmap(i3)(s2)
+                s3, vf3 = run_scan(c3, v0("fc1"))
+            with jax.named_scope("fc2"):
+                c4 = jax.vmap(i4)(s3)
+                s4, vf4 = run_scan(c4, v0("fc2"))
         out_spikes = jnp.transpose(s4, (1, 0, 2))
         out_membrane = jnp.zeros_like(out_spikes)  # not tracked in this mode
         # Layer outputs are (T, B, ...): batch axis 1.

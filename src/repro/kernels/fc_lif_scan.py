@@ -218,6 +218,7 @@ def fc_lif_scan_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fc_lif_scan",
     )(spk, w_p, v0_p)
 
     out = out[:t, :, :n]

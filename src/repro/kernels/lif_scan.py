@@ -180,6 +180,7 @@ def lif_scan_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="lif_scan",
     )(cur, v0r)
 
     spikes = spikes[:t].reshape(t, (n + n_pad))[:, :n].reshape(orig_shape)

@@ -153,5 +153,6 @@ def ternary_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ternary_matmul",
     )(x, w_packed, scale)
     return out[:m, :n]

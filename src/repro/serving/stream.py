@@ -154,6 +154,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core._api import (EngineConfig, RecoveryConfig,
                              suppress_api_deprecations,
                              warn_deprecated_call)
@@ -413,6 +414,10 @@ class _InflightLane:
     re-queue its windows under their original sequence numbers;
     ``prev_carry`` maps each dispatched stateful stream to the device
     slice of its PRE-window carry, the value quarantine rolls back to.
+
+    ``step`` is the number of the ``step()`` call that dispatched the
+    record, so that its collect -- a later call's, when pipelined -- is
+    traced under the same step id.
     """
 
     lane: "EngineLane"
@@ -422,6 +427,7 @@ class _InflightLane:
     pending: Any
     items: Optional[List[Optional["_Queued"]]] = None
     prev_carry: Optional[Dict[Hashable, Any]] = None
+    step: int = -1
 
 
 @dataclasses.dataclass
@@ -624,6 +630,19 @@ class DeadlinePolicy(FairQuantumPolicy):
         """Drop the stream's aging counter (engine calls this on retire
         so a reused id starts with fresh aging)."""
         self._waited.pop(stream_id, None)
+
+
+def _pack(lane: "EngineLane", heads: List):
+    """The lane's engine packs one head per slot into its fixed batch,
+    traced as a ``pack`` span whose value is the bytes of the numpy
+    arrays the batch holds (0 for a batch that is not an object of
+    arrays)."""
+    with tracing.span("pack", lane=lane.modality) as span:
+        batch = lane.engine.prepare(heads, batch_size=len(lane.slots))
+        span.value = sum(
+            a.nbytes for a in getattr(batch, "__dict__", {}).values()
+            if isinstance(a, np.ndarray))
+    return batch
 
 
 # ----------------------------------------------------------------------
@@ -1156,9 +1175,7 @@ class StreamEngine:
         self._handles: Dict[Hashable, StreamHandle] = {}
         self._auto_id = 0
         self.stream_stats: Dict[Hashable, StreamStats] = {}
-        self.stats: Dict[str, float] = {
-            "steps": 0, "windows": 0, "wall_s": 0.0,
-        }
+        self.stats: Dict[str, int] = {"steps": 0, "windows": 0}
         # The clock finite deadlines are measured against for miss
         # telemetry (NOT for scheduling -- policies only order by
         # deadline value). Defaults to wall time; fleet drivers and
@@ -1774,7 +1791,9 @@ class StreamEngine:
         ``commit(new_state)`` thunk that advances the lane's state
         tracking -- called only after EVERY lane's phase 1 succeeded, so
         a failed synchronous step leaves carried state as untouched as it
-        leaves the queues.
+        leaves the queues. ``commit`` returns the number of eager array
+        operations it issued (one row slice per leaf of each parked
+        carry), the value of its ``state_park`` span.
         """
         if not lane.supports_state or not lane.stateful:
             # No stream on this lane carries state: serve it through the
@@ -1784,6 +1803,15 @@ class StreamEngine:
             # pipelined deferred-"batch" fallback it would lose on the
             # stateful path.
             return None, None
+        with tracing.span("state_gather", lane=lane.modality) as span:
+            state_in, commit, span.value = self._gather_state(lane)
+        return state_in, commit
+
+    def _gather_state(self, lane: EngineLane):
+        """:meth:`_lane_state_in` for a lane with stateful streams; also
+        returns the number of eager array operations the row rebuild
+        issued (one per row taken from a buffer, one stack per leaf; 0 on
+        the identity fast path)."""
         if lane.state is None:       # first stateful dispatch: zero state
             lane.zero_state = lane.engine.init_state(len(lane.slots))
             lane.state = lane.zero_state
@@ -1809,6 +1837,7 @@ class StreamEngine:
         # results are discarded), so they never force a rebuild.
         identity = all(sid is _FREE or s == ("row", i)
                        for i, (sid, s) in enumerate(zip(slots, src)))
+        ops = 0
         if identity:
             state_in = lane.state
         else:
@@ -1828,12 +1857,15 @@ class StreamEngine:
                         rows.append(parked[s[1]][li])
                 new_leaves.append(jnp.stack(rows))
             state_in = jax.tree_util.tree_unflatten(treedef, new_leaves)
+            taken = sum(1 for s in src if s is None or s[0] == "row")
+            ops = len(leaves) * (taken + 1)
 
         old_state = lane.state
         old_owners = list(lane.state_streams)
         scheduled = {sid for sid in slots if sid is not _FREE}
 
-        def commit(new_state):
+        def commit(new_state) -> int:
+            parked = 0
             for j, owner in enumerate(old_owners):
                 if owner is _FREE or owner in scheduled:
                     continue
@@ -1842,6 +1874,7 @@ class StreamEngine:
                 # stream to whichever slot it wins next.
                 lane.parked[owner] = jax.tree_util.tree_map(
                     lambda a: a[j], old_state)
+                parked += 1
             for sid in scheduled:
                 lane.parked.pop(sid, None)
             lane.state = new_state
@@ -1849,8 +1882,9 @@ class StreamEngine:
                 sid if (sid is not _FREE and sid in lane.stateful)
                 else _FREE
                 for sid in slots]
+            return parked * len(jax.tree_util.tree_leaves(old_state))
 
-        return state_in, commit
+        return state_in, commit, ops
 
     # -- scheduling ------------------------------------------------------
 
@@ -1872,8 +1906,19 @@ class StreamEngine:
         The result sequence is bitwise identical to synchronous mode;
         windows are consumed at dispatch, so device failures surface at
         the later collect instead of at this call.
+
+        Each call is traced as a ``step`` span (:mod:`repro.tracing`)
+        whose step id is the call's number and whose value is the
+        windows returned.
         """
-        t0 = time.perf_counter()
+        self._dispatch_no += 1
+        with tracing.span("step", lane="", step=self._dispatch_no) as span:
+            out = self._step()
+            span.value = len(out)
+        return out
+
+    def _step(self) -> List[StreamResult]:
+        """The body of :meth:`step`, inside its span."""
         if self.pipeline_depth == 0:
             ran = self._dispatch(eager=True)
             failed = self._take_failures()
@@ -1898,7 +1943,6 @@ class StreamEngine:
         # A no-op call (nothing dispatched, nothing collected) does not
         # count as a step; a failed one raises before reaching here.
         self.stats["steps"] += 1
-        self.stats["wall_s"] += time.perf_counter() - t0
         return out
 
     def _dispatch(self, *, eager: bool) -> List[_InflightLane]:
@@ -1916,34 +1960,8 @@ class StreamEngine:
         carried-state tracking only after every lane's dispatch
         succeeded.
         """
-        self._dispatch_no += 1
-        active: List[EngineLane] = []
-        for lane in self._lanes.values():
-            if self.recovery is not None:
-                if lane.dead:
-                    # Fail-fast: a dead lane never calls its engine;
-                    # queued windows are dead-lettered immediately so
-                    # paired fusion ticks keep completing (degraded)
-                    # until replace_lane_engine installs a rebuild.
-                    self._fail_fast_lane(lane)
-                    continue
-                if lane.cooldown > 0:
-                    # Deterministic backoff: sit out whole engine steps
-                    # (not wall time) after a failed lane step.
-                    lane.cooldown -= 1
-                    continue
-            self.policy.assign(lane)
-            active.append(lane)
-        if self._pairs and self.coschedule:
-            self._coschedule(active)
-        work: List[tuple] = []
-        for lane in active:
-            heads = [
-                lane.queues[sid][0].item if sid is not _FREE else None
-                for sid in lane.slots
-            ]
-            if any(w is not None for w in heads):
-                work.append((lane, heads))
+        with tracing.span("assign"):
+            work = self._assign()
         ran: List[_InflightLane] = []
         state_commits: List[tuple] = []
         if self.megastep and len(work) == 2:
@@ -1987,8 +2005,9 @@ class StreamEngine:
                 state_commits.append(commit)
         # Commit: every lane dispatched -- pop the served heads and
         # advance each lane's carried state.
-        for commit, new_state in state_commits:
-            commit(new_state)
+        for lane, commit, new_state in state_commits:
+            with tracing.span("state_park", lane=lane.modality) as span:
+                span.value = commit(new_state)
         for rec in ran:
             lane = rec.lane
             rec.items = [None] * len(rec.entries)
@@ -2005,50 +2024,86 @@ class StreamEngine:
                     self._note_pair_dispatch(sid, entry.seq)
         return ran
 
+    def _assign(self) -> List[tuple]:
+        """Phase 1 of :meth:`_dispatch`: the policy assigns every
+        servable lane's slots (fail-fast and backoff first, with
+        recovery), co-scheduling fixes up fusion pairs; returns
+        ``(lane, heads)`` for each lane with a queued head in a slot."""
+        active: List[EngineLane] = []
+        for lane in self._lanes.values():
+            if self.recovery is not None:
+                if lane.dead:
+                    # Fail-fast: a dead lane never calls its engine;
+                    # queued windows are dead-lettered immediately so
+                    # paired fusion ticks keep completing (degraded)
+                    # until replace_lane_engine installs a rebuild.
+                    self._fail_fast_lane(lane)
+                    continue
+                if lane.cooldown > 0:
+                    # Deterministic backoff: sit out whole engine steps
+                    # (not wall time) after a failed lane step.
+                    lane.cooldown -= 1
+                    continue
+            self.policy.assign(lane)
+            active.append(lane)
+        if self._pairs and self.coschedule:
+            self._coschedule(active)
+        work: List[tuple] = []
+        for lane in active:
+            heads = [
+                lane.queues[sid][0].item if sid is not _FREE else None
+                for sid in lane.slots
+            ]
+            if any(w is not None for w in heads):
+                work.append((lane, heads))
+        return work
+
     def _dispatch_lane(self, lane: EngineLane, heads: List,
                        eager: bool) -> tuple:
         """One lane's dispatch (phase 2 of :meth:`_dispatch`): returns
         ``(record, state_commit_or_None)``; raises with the lane's
         queues untouched."""
-        batch = lane.engine.prepare(heads, batch_size=len(lane.slots))
+        batch = _pack(lane, heads)
         key = lane.engine.shape_key(batch)
         state_in, state_commit = self._lane_state_in(lane)
         dispatch = getattr(lane.engine, "infer_dispatch", None)
         collect = getattr(lane.engine, "infer_collect", None)
         has_split = dispatch is not None and collect is not None
         new_state = None
-        if eager or (state_in is not None and not has_split):
-            # Synchronous infer. A stateful engine WITHOUT the async
-            # split also lands here under pipelining: its carry must
-            # advance in dispatch order, so its infer cannot wait for
-            # the (later) collect.
-            if state_in is None:
-                # Stateless lanes ride the engines' legacy call form by
-                # design; the deprecation nudge is for end users.
-                with suppress_api_deprecations():
-                    results = lane.engine.infer(batch)
-                kind, pending = "results", results
+        with tracing.span("launch", lane=lane.modality):
+            if eager or (state_in is not None and not has_split):
+                # Synchronous infer. A stateful engine WITHOUT the async
+                # split also lands here under pipelining: its carry must
+                # advance in dispatch order, so its infer cannot wait
+                # for the (later) collect.
+                if state_in is None:
+                    # Stateless lanes ride the engines' legacy call form
+                    # by design; the deprecation nudge is for end users.
+                    with suppress_api_deprecations():
+                        results = lane.engine.infer(batch)
+                    kind, pending = "results", results
+                else:
+                    results, new_state = lane.engine.infer(batch, state_in)
+                    kind, pending = "results", results
+            elif has_split:
+                if state_in is None:
+                    kind, pending = "handle", dispatch(batch)
+                else:
+                    # Async dispatch: new_state is a pytree of device
+                    # futures, threaded into the NEXT dispatch without
+                    # ever blocking on (or copying to) the host.
+                    pending, new_state = dispatch(batch, state_in)
+                    kind = "handle"
             else:
-                results, new_state = lane.engine.infer(batch, state_in)
-                kind, pending = "results", results
-        elif has_split:
-            if state_in is None:
-                kind, pending = "handle", dispatch(batch)
-            else:
-                # Async dispatch: new_state is a pytree of device
-                # futures, threaded into the NEXT dispatch without ever
-                # blocking on (or copying to) the host.
-                pending, new_state = dispatch(batch, state_in)
-                kind = "handle"
-        else:
-            kind, pending = "batch", batch
+                kind, pending = "batch", batch
         rec = _InflightLane(
             lane=lane, key=key,
             entries=[None if w is None else slot
                      for slot, w in enumerate(heads)],
             kind=kind, pending=pending,
-            prev_carry=self._prev_carry(lane, heads, state_in))
-        commit = ((state_commit, new_state)
+            prev_carry=self._prev_carry(lane, heads, state_in),
+            step=self._dispatch_no)
+        commit = ((lane, state_commit, new_state)
                   if state_commit is not None else None)
         return rec, commit
 
@@ -2162,14 +2217,15 @@ class StreamEngine:
         cache_key = (ev_key, fr_key)
         exe = self._mega_exe.get(cache_key)
         if exe is None:
-            ev_run, ev_abs = ev_lane.engine._mega_parts(ev_key)
-            fr_run, fr_abs = fr_lane.engine._mega_parts(fr_key)
+            with tracing.span("compile", value=1):
+                ev_run, ev_abs = ev_lane.engine._mega_parts(ev_key)
+                fr_run, fr_abs = fr_lane.engine._mega_parts(fr_key)
 
-            def mega(ev_args, fr_args):
-                return ev_run(*ev_args), fr_run(*fr_args)
+                def mega(ev_args, fr_args):
+                    return ev_run(*ev_args), fr_run(*fr_args)
 
-            exe = jax.jit(mega).lower(ev_abs, fr_abs).compile()
-            self._mega_exe[cache_key] = exe
+                exe = jax.jit(mega).lower(ev_abs, fr_abs).compile()
+                self._mega_exe[cache_key] = exe
         return exe
 
     def _mega_dispatch(self, work: List[tuple], eager: bool) -> tuple:
@@ -2181,31 +2237,30 @@ class StreamEngine:
         by_mod = {lane.modality: (lane, heads) for lane, heads in work}
         ev_lane, ev_heads = by_mod["event"]
         fr_lane, fr_heads = by_mod["frame"]
-        ev_batch = ev_lane.engine.prepare(
-            ev_heads, batch_size=len(ev_lane.slots))
+        ev_batch = _pack(ev_lane, ev_heads)
         ev_key = ev_lane.engine.shape_key(ev_batch)
-        fr_batch = fr_lane.engine.prepare(
-            fr_heads, batch_size=len(fr_lane.slots))
+        fr_batch = _pack(fr_lane, fr_heads)
         fr_key = fr_lane.engine.shape_key(fr_batch)
         ev_state, ev_commit = self._lane_state_in(ev_lane)
         fr_state, fr_commit = self._lane_state_in(fr_lane)
-        exe = self._mega_executable(ev_lane, fr_lane, ev_key, fr_key)
-        ev_out, fr_out = exe(
-            ev_lane.engine._mega_args(ev_batch, ev_state),
-            fr_lane.engine._mega_args(fr_batch, fr_state))
-        ev_pending, ev_new = ev_lane.engine._mega_split(
-            ev_out, ev_batch, ev_state)
-        fr_pending, fr_new = fr_lane.engine._mega_split(
-            fr_out, fr_batch, fr_state)
-        if eager:
-            # Synchronous mode stays retry-safe: materialize BOTH
-            # wings' results before any queue state moves.
-            ev_kind, ev_pending = "results", ev_lane.engine.infer_collect(
-                ev_pending)
-            fr_kind, fr_pending = "results", fr_lane.engine.infer_collect(
-                fr_pending)
-        else:
-            ev_kind = fr_kind = "handle"
+        with tracing.span("launch"):
+            exe = self._mega_executable(ev_lane, fr_lane, ev_key, fr_key)
+            ev_out, fr_out = exe(
+                ev_lane.engine._mega_args(ev_batch, ev_state),
+                fr_lane.engine._mega_args(fr_batch, fr_state))
+            ev_pending, ev_new = ev_lane.engine._mega_split(
+                ev_out, ev_batch, ev_state)
+            fr_pending, fr_new = fr_lane.engine._mega_split(
+                fr_out, fr_batch, fr_state)
+            if eager:
+                # Synchronous mode stays retry-safe: materialize BOTH
+                # wings' results before any queue state moves.
+                ev_kind, ev_pending = (
+                    "results", ev_lane.engine.infer_collect(ev_pending))
+                fr_kind, fr_pending = (
+                    "results", fr_lane.engine.infer_collect(fr_pending))
+            else:
+                ev_kind = fr_kind = "handle"
         recs: List[_InflightLane] = []
         commits: List[tuple] = []
         for lane, heads, key, kind, pending, state_in, commit, new in (
@@ -2218,9 +2273,10 @@ class StreamEngine:
                 entries=[None if w is None else slot
                          for slot, w in enumerate(heads)],
                 kind=kind, pending=pending,
-                prev_carry=self._prev_carry(lane, heads, state_in)))
+                prev_carry=self._prev_carry(lane, heads, state_in),
+                step=self._dispatch_no))
             if commit is not None:
-                commits.append((commit, new))
+                commits.append((lane, commit, new))
         # Records in lane declaration order, exactly as the per-lane
         # path emits them, so result ordering is bitwise unchanged.
         order = {m: i for i, m in enumerate(self._lanes)}
@@ -2248,7 +2304,15 @@ class StreamEngine:
         return out
 
     def _collect_one(self, rec: _InflightLane) -> List[StreamResult]:
-        """Collect one lane's record of one dispatched step."""
+        """Collect one lane's record of one dispatched step, traced as a
+        ``collect`` span under the step id of its dispatch; the
+        per-stream stats loop is an ``account`` span of value 0 (the
+        engine's own ``account`` span counts the windows)."""
+        with tracing.span("collect", lane=rec.lane.modality, step=rec.step):
+            return self._collect_record(rec)
+
+    def _collect_record(self, rec: _InflightLane) -> List[StreamResult]:
+        """The body of :meth:`_collect_one`."""
         lane = rec.lane
         try:
             if rec.kind == "results":
@@ -2264,40 +2328,41 @@ class StreamEngine:
             return self._recover_record(rec, exc)
         lane.shape_keys.add(rec.key)
         lane.fail_streak = 0
-        out: List[StreamResult] = []
-        wall_t = time.perf_counter()
-        rcfg = self.recovery
-        for slot, entry in enumerate(rec.entries):
-            if entry is None:
-                continue
-            sid, seq, deadline = entry
-            res = results[slot]
-            if (rcfg is not None and rcfg.quarantine_nonfinite
-                    and res.logits is not None
-                    and not np.all(np.isfinite(np.asarray(res.logits)))):
-                # Poison: NaNs are deterministic, a retry would just
-                # recompute them -- quarantine immediately, roll the
-                # carry back, keep the stream alive.
-                out.append(self._quarantine_entry(
-                    rec, slot, "non-finite logits"))
-                continue
-            lane.retries.pop((sid, seq), None)
-            st = self.stream_stats[sid]
-            st.windows += 1
-            st.energy_mj += res.energy_mj
-            st.latency_ms_sum += res.latency_ms
-            st.realtime_windows += int(res.realtime)
-            # Deadline-miss telemetry: a finite deadline is an
-            # instant on the engine's deadline_clock; collecting the
-            # window after that instant is a miss. Feeds the sliding
-            # per-stream horizon the fleet control plane reads.
-            missed = (None if deadline is None
-                      else self.deadline_clock() > deadline)
-            st.note_completion(wall_t, st.queued, missed)
-            out.append(StreamResult(
-                stream_id=sid, seq=seq, result=res,
-                modality=lane.modality))
-            self.stats["windows"] += 1
+        with tracing.span("account"):
+            out: List[StreamResult] = []
+            wall_t = time.perf_counter()
+            rcfg = self.recovery
+            for slot, entry in enumerate(rec.entries):
+                if entry is None:
+                    continue
+                sid, seq, deadline = entry
+                res = results[slot]
+                if (rcfg is not None and rcfg.quarantine_nonfinite
+                        and res.logits is not None
+                        and not np.all(np.isfinite(np.asarray(res.logits)))):
+                    # Poison: NaNs are deterministic, a retry would just
+                    # recompute them -- quarantine immediately, roll the
+                    # carry back, keep the stream alive.
+                    out.append(self._quarantine_entry(
+                        rec, slot, "non-finite logits"))
+                    continue
+                lane.retries.pop((sid, seq), None)
+                st = self.stream_stats[sid]
+                st.windows += 1
+                st.energy_mj += res.energy_mj
+                st.latency_ms_sum += res.latency_ms
+                st.realtime_windows += int(res.realtime)
+                # Deadline-miss telemetry: a finite deadline is an
+                # instant on the engine's deadline_clock; collecting the
+                # window after that instant is a miss. Feeds the sliding
+                # per-stream horizon the fleet control plane reads.
+                missed = (None if deadline is None
+                          else self.deadline_clock() > deadline)
+                st.note_completion(wall_t, st.queued, missed)
+                out.append(StreamResult(
+                    stream_id=sid, seq=seq, result=res,
+                    modality=lane.modality))
+                self.stats["windows"] += 1
         return out
 
     # -- fault recovery --------------------------------------------------

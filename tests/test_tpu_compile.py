@@ -108,3 +108,36 @@ def test_ternary_matmul_compiles_for_v5e(one_chip, no_persistent_cache,
          ((n,), jnp.float32)],
         one_chip)
     assert "tpu_custom_call" in text
+
+
+def test_served_event_step_keeps_kernels_findable(one_chip,
+                                                  no_persistent_cache,
+                                                  monkeypatch):
+    """The served event step, as ``BatchedClosedLoop._executable``
+    compiles it, for a described v5e at the Table II widths: the step
+    keeps its module name and the benchmark's trace reduction
+    (``bench.lib.trace.hlo_index``) still finds each Pallas kernel by its
+    source file, with the kernels' ``name=`` and the step's named
+    scopes."""
+    import importlib
+    import sys
+
+    from repro.core import BatchedClosedLoop, init_snn
+    from repro.kernels import lif_scan
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from bench.lib import trace
+
+    for name in ("repro.kernels.fc_lif_scan", "repro.kernels.lif_scan"):
+        monkeypatch.setattr(importlib.import_module(name), "use_interpret",
+                            lambda i=None: False)
+    eng = BatchedClosedLoop(init_snn(jax.random.PRNGKey(0), CONFIG), CONFIG,
+                            lif_scan_fn=lif_scan, fuse_fc=True)
+    eng._zero_state_for(1)        # on the host, before the scope below
+    (device,) = one_chip.device_set
+    with jax.default_device(device):
+        text = eng._executable((1, 1024, 300_000)).as_text()
+    assert text.startswith(f"HloModule {trace.STEP_MODULE},")
+    kernels = trace.hlo_index(text)[1]
+    assert sorted(kernels.values()) == ["fc_lif_scan", "fc_lif_scan",
+                                        "lif_scan", "lif_scan"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 4

@@ -51,7 +51,11 @@ slots, and on every dispatch each slot is fed the carry of the stream it
 currently holds. State follows the STREAM, not the slot index: when the
 policy moves a stream to another slot (rotation, deadline preemption) its
 carry is gathered along; when a stream loses its slot its carry is
-parked and re-attached on the next slot it wins. Slots are always
+parked and re-attached on the next slot it wins. Parked carries live in
+a per-lane device carry store, one home row per parked stream, and one
+compiled program per step re-lays the slots and parks the carries of
+streams that lost theirs (none when every carry stays in its row).
+Slots are always
 zeroed on admission -- a stream newly admitted into a slot previously
 held by another (a "dirty" slot) starts from the cold-start state,
 bitwise identical to a fresh B=1 run -- and stateless streams are fed
@@ -160,6 +164,7 @@ from repro.core._api import (EngineConfig, RecoveryConfig,
                              warn_deprecated_call)
 from repro.core.energy import KrakenModel
 from repro.core.engine import InferenceEngine
+from repro.core.events import next_pow2
 from repro.core.pipeline import (BatchedClosedLoop, ClosedLoopResult,
                                  _check_slot_divisible, export_state_slot,
                                  import_state_slot)
@@ -412,8 +417,9 @@ class _InflightLane:
     :class:`~repro.core._api.RecoveryConfig`): ``items`` keeps the
     popped :class:`_Queued` objects slot-aligned so a failed record can
     re-queue its windows under their original sequence numbers;
-    ``prev_carry`` maps each dispatched stateful stream to the device
-    slice of its PRE-window carry, the value quarantine rolls back to.
+    ``prev_carry`` maps each dispatched stateful stream to where its
+    PRE-window carry sits, ``(state fed in, row)``: the value quarantine
+    rolls back to.
 
     ``step`` is the number of the ``step()`` call that dispatched the
     record, so that its collect -- a later call's, when pipelined -- is
@@ -443,9 +449,15 @@ class EngineLane:
     ``state`` is the slot-major device pytree fed to the NEXT dispatch
     row-aligned with ``slots`` at that dispatch; ``state_streams`` tracks,
     per row, which stateful stream's carry the row holds (rows of
-    stateless or free slots are dead and zeroed on reuse); ``parked``
-    holds the carries of stateful streams that currently have no slot;
-    ``stateful`` is the set of streams that opted into carry at submit.
+    stateless or free slots are dead and zeroed on reuse); ``store`` is
+    the device carry store, the engine's state pytree with
+    ``capacity + 1`` rows, whose last row stays the cold-start state;
+    ``parked`` maps each stateful stream that currently has no slot to
+    the home row of ``store`` holding its carry; ``stateful`` is the set
+    of streams that opted into carry at submit. ``capacity`` is the
+    next power of two at or above the lane's stateful streams (it only
+    grows, by doubling), and ``move_exe`` caches the compiled
+    state-move program per ``(slots, capacity)``.
     Invariant: a stateful stream's carry lives in exactly one of a state
     row or ``parked`` (or nowhere, meaning cold start).
     """
@@ -461,8 +473,12 @@ class EngineLane:
     stateful: set = dataclasses.field(default_factory=set)
     state: Any = None
     state_streams: List[Hashable] = dataclasses.field(default_factory=list)
-    parked: Dict[Hashable, Any] = dataclasses.field(default_factory=dict)
+    parked: Dict[Hashable, int] = dataclasses.field(default_factory=dict)
     zero_state: Any = None
+    store: Any = None
+    capacity: int = 0
+    move_exe: Dict[tuple, Callable] = dataclasses.field(
+        default_factory=dict)
     # Fault-recovery state (only ever mutated when the engine carries a
     # RecoveryConfig; all-defaults otherwise).
     dead: bool = False            # fail-fast mode until engine replaced
@@ -645,6 +661,32 @@ def _pack(lane: "EngineLane", heads: List):
     return batch
 
 
+def _move_carries(state, store, idx):
+    """The state-move program, over flat lists of state leaves: each
+    ``state`` leaf has one row per slot, each ``store`` leaf
+    ``capacity + 1`` rows. ``idx`` is int32 ``(3, slots)``: row 0 says
+    where each slot's carry comes from, as a row of ``concat(state,
+    store)`` (a buffer row, a home row or the cold-start row); rows 1
+    and 2 pair the buffer rows of streams that lost their slot with the
+    home rows they park in, padded with home rows past the store, which
+    the scatter drops. Returns ``(state_in, store')``; every row is an
+    exact copy."""
+    state_in, stored = [], []
+    for a, s in zip(state, store):
+        state_in.append(jnp.take(jnp.concatenate([a, s]), idx[0], axis=0,
+                                 mode="clip"))
+        stored.append(s.at[idx[2]].set(a[idx[1]], mode="drop"))
+    return state_in, stored
+
+
+def _free_homes(lane: "EngineLane"):
+    """The store's home rows no parked stream holds, in order. There is
+    one for each stateful stream not parked, since the store has a row
+    for every stateful stream of the lane."""
+    held = set(lane.parked.values())
+    return (r for r in range(lane.capacity) if r not in held)
+
+
 # ----------------------------------------------------------------------
 # The session-handle serving surface.
 # ----------------------------------------------------------------------
@@ -658,12 +700,11 @@ def _export_carry(engine: InferenceEngine, state, slot: int):
 
 
 def _import_carry(engine: InferenceEngine, payload):
-    """An exported carry back on device, in the serving layer's parked
-    (per-stream, no slot axis) form, via the engine's duck-typed
-    ``import_state`` splicing into a fresh 1-slot zero state."""
+    """An exported carry back on device as a 1-slot state (row 0), via
+    the engine's duck-typed ``import_state`` splicing into a fresh
+    1-slot zero state."""
     import_ = getattr(engine, "import_state", import_state_slot)
-    lifted = import_(engine.init_state(1), 0, payload)
-    return jax.tree_util.tree_map(lambda a: a[0], lifted)
+    return import_(engine.init_state(1), 0, payload)
 
 
 class StreamHandle:
@@ -823,9 +864,8 @@ class StreamHandle:
             if row is not None:
                 payload = _export_carry(lane.engine, lane.state, row)
             elif sid in lane.parked:
-                lifted = jax.tree_util.tree_map(lambda a: a[None],
-                                                lane.parked[sid])
-                payload = _export_carry(lane.engine, lifted, 0)
+                payload = _export_carry(lane.engine, lane.store,
+                                        lane.parked[sid])
             # else: cold start -- a None payload restores to zero state.
         return StreamCheckpoint(
             stream_id=sid, modality=lane.modality, stateful=self.stateful,
@@ -881,7 +921,8 @@ class StreamHandle:
             lane.engine.duration_us = prev_duration
             raise
         if ckpt.state is not None:
-            lane.parked[sid] = _import_carry(lane.engine, ckpt.state)
+            eng._park_rows(lane, _import_carry(lane.engine, ckpt.state),
+                           [(sid, 0)])
         eng._seq[sid] = int(ckpt.next_seq)
         if self.deadline is None:
             self.deadline = ckpt.deadline
@@ -1398,13 +1439,13 @@ class StreamEngine:
             return []
         # Park every live carry: the state buffer is shaped by the slot
         # count, so it is rebuilt (lazily, from parked + zero rows) at
-        # the next stateful dispatch. Parking slices whatever the rows
+        # the next stateful dispatch. Parking copies whatever the rows
         # hold -- including pipelined async-dispatch futures.
         if lane.state is not None:
-            for j, owner in enumerate(lane.state_streams):
-                if owner is not _FREE and owner in lane.stateful:
-                    lane.parked[owner] = jax.tree_util.tree_map(
-                        lambda a, j=j: a[j], lane.state)
+            live = [(owner, j) for j, owner in enumerate(lane.state_streams)
+                    if owner is not _FREE and owner in lane.stateful]
+            if live:
+                self._park_rows(lane, lane.state, live)
             lane.state = None
             lane.zero_state = None
         lane.state_streams = [_FREE] * slots
@@ -1516,10 +1557,7 @@ class StreamEngine:
             if rest:
                 remaining.append(rest)
         self._inflight = remaining
-        lane.state = None
-        lane.zero_state = None
-        lane.state_streams = [_FREE] * len(lane.slots)
-        lane.parked.clear()
+        self._drop_carries(lane)
         self._requeue(lane, requeue)
         return len(requeue)
 
@@ -1583,10 +1621,8 @@ class StreamEngine:
         lane.engine = engine
         lane.supports_state = hasattr(engine, "init_state")
         lane.shape_keys = set()
-        lane.state = None
-        lane.zero_state = None
-        lane.state_streams = [_FREE] * len(lane.slots)
-        lane.parked.clear()
+        self._drop_carries(lane)
+        lane.move_exe.clear()
         lane.dead = False
         lane.fail_streak = 0
         lane.cooldown = 0
@@ -1791,9 +1827,9 @@ class StreamEngine:
         ``commit(new_state)`` thunk that advances the lane's state
         tracking -- called only after EVERY lane's phase 1 succeeded, so
         a failed synchronous step leaves carried state as untouched as it
-        leaves the queues. ``commit`` returns the number of eager array
-        operations it issued (one row slice per leaf of each parked
-        carry), the value of its ``state_park`` span.
+        leaves the queues. The planning is a ``state_gather`` span whose
+        value is the state-move programs it issued (1 or 0); ``commit``
+        only swaps references.
         """
         if not lane.supports_state or not lane.stateful:
             # No stream on this lane carries state: serve it through the
@@ -1809,72 +1845,64 @@ class StreamEngine:
 
     def _gather_state(self, lane: EngineLane):
         """:meth:`_lane_state_in` for a lane with stateful streams; also
-        returns the number of eager array operations the row rebuild
-        issued (one per row taken from a buffer, one stack per leaf; 0 on
-        the identity fast path)."""
+        returns the number of state-move programs issued: 0 on the
+        identity fast path (every slotted stream's carry already in its
+        own row, nothing to park) and for a state with no leaves, else 1.
+        """
         if lane.state is None:       # first stateful dispatch: zero state
             lane.zero_state = lane.engine.init_state(len(lane.slots))
             lane.state = lane.zero_state
-
+        self._ensure_store(lane)
         slots = list(lane.slots)
+        n = len(slots)
         pos = {owner: j for j, owner in enumerate(lane.state_streams)
                if owner is not _FREE}
-        # Per slot: ("row", j) = carry already in the buffer at row j;
-        # ("parked", sid) = carry parked off-buffer; None = zero row
-        # (free slot, stateless stream, or cold-start stateful stream).
-        src: List[Any] = []
+        scheduled = {sid for sid in slots if sid is not _FREE}
+        # Per slot, the row of concat(state, store) its carry comes from:
+        # its own buffer row, its home row, or the cold-start row (free
+        # slot, stateless stream, or cold-start stateful stream).
+        cold = n + lane.capacity
+        src = []
         for sid in slots:
             if sid is _FREE or sid not in lane.stateful:
-                src.append(None)
+                src.append(cold)
             elif sid in pos:
-                src.append(("row", pos[sid]))
+                src.append(pos[sid])
             elif sid in lane.parked:
-                src.append(("parked", sid))
+                src.append(n + lane.parked[sid])
             else:
-                src.append(None)
+                src.append(cold)
+        # Streams that lose their slot this step park their carry (the
+        # PRE-dispatch row) in a home row no parked stream holds.
+        free = _free_homes(lane)
+        parks = [(owner, j, next(free))
+                 for j, owner in enumerate(lane.state_streams)
+                 if owner is not _FREE and owner not in scheduled]
         # Fast path: every occupied slot is a stateful stream whose carry
-        # already sits in its own row. Free slots' rows are dead (their
-        # results are discarded), so they never force a rebuild.
-        identity = all(sid is _FREE or s == ("row", i)
-                       for i, (sid, s) in enumerate(zip(slots, src)))
-        ops = 0
-        if identity:
-            state_in = lane.state
-        else:
-            leaves, treedef = jax.tree_util.tree_flatten(lane.state)
-            zeros = jax.tree_util.tree_flatten(lane.zero_state)[0]
-            parked = {s[1]: jax.tree_util.tree_flatten(lane.parked[s[1]])[0]
-                      for s in src if s is not None and s[0] == "parked"}
-            new_leaves = []
-            for li, leaf in enumerate(leaves):
-                rows = []
-                for s in src:
-                    if s is None:
-                        rows.append(zeros[li][0])
-                    elif s[0] == "row":
-                        rows.append(leaf[s[1]])
-                    else:
-                        rows.append(parked[s[1]][li])
-                new_leaves.append(jnp.stack(rows))
-            state_in = jax.tree_util.tree_unflatten(treedef, new_leaves)
-            taken = sum(1 for s in src if s is None or s[0] == "row")
-            ops = len(leaves) * (taken + 1)
+        # already sits in its own row, and no carry leaves the buffer.
+        # Free slots' rows are dead (their results are discarded), so
+        # they never force a move.
+        identity = not parks and all(
+            sid is _FREE or s == i for i, (sid, s) in enumerate(zip(slots,
+                                                                  src)))
+        leaves, treedef = jax.tree_util.tree_flatten(lane.state)
+        state_in, store, programs = lane.state, lane.store, 0
+        if leaves and not identity:
+            idx = np.zeros((3, n), np.int32)
+            idx[0] = src
+            idx[2] = lane.capacity + 1
+            for k, (_, j, row) in enumerate(parks):
+                idx[1, k], idx[2, k] = j, row
+            moved, stored = self._move_executable(lane)(
+                leaves, jax.tree_util.tree_leaves(lane.store), idx)
+            state_in = jax.tree_util.tree_unflatten(treedef, moved)
+            store = jax.tree_util.tree_unflatten(treedef, stored)
+            programs = 1
 
-        old_state = lane.state
-        old_owners = list(lane.state_streams)
-        scheduled = {sid for sid in slots if sid is not _FREE}
-
-        def commit(new_state) -> int:
-            parked = 0
-            for j, owner in enumerate(old_owners):
-                if owner is _FREE or owner in scheduled:
-                    continue
-                # The stream lost its slot this step: park its carry
-                # (from the PRE-dispatch buffer) so it can follow the
-                # stream to whichever slot it wins next.
-                lane.parked[owner] = jax.tree_util.tree_map(
-                    lambda a: a[j], old_state)
-                parked += 1
+        def commit(new_state) -> None:
+            lane.store = store
+            for owner, _, row in parks:
+                lane.parked[owner] = row
             for sid in scheduled:
                 lane.parked.pop(sid, None)
             lane.state = new_state
@@ -1882,9 +1910,98 @@ class StreamEngine:
                 sid if (sid is not _FREE and sid in lane.stateful)
                 else _FREE
                 for sid in slots]
-            return parked * len(jax.tree_util.tree_leaves(old_state))
 
-        return state_in, commit, ops
+        return state_in, commit, programs
+
+    def _ensure_store(self, lane: EngineLane) -> None:
+        """Give the lane a carry store with a home row for each of its
+        stateful streams: ``capacity`` is the next power of two at or
+        above their count. A store that is too small grows by doubling;
+        rows keep their index, so parked carries stay where they are
+        (the old cold-start row becomes a free home row)."""
+        need = next_pow2(len(lane.stateful), floor=1)
+        if lane.store is not None and lane.capacity >= need:
+            return
+        if lane.store is None:
+            store = lane.engine.init_state(need + 1)
+        else:
+            store = jax.tree_util.tree_map(
+                lambda s, z: jnp.concatenate([s, z]), lane.store,
+                lane.engine.init_state(need - lane.capacity))
+        lane.store = self._place_store(store)
+        lane.capacity = need
+
+    def _place_store(self, store):
+        """A carry store where the state-move program expects it:
+        replicated over the mesh on a mesh-attached engine (its row
+        count need not divide over the slot axis), as it is otherwise."""
+        if self.mesh is None:
+            return store
+        return jax.device_put(store, self._replicated())
+
+    def _replicated(self):
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(self.mesh, PartitionSpec())
+
+    def _park_rows(self, lane: EngineLane, state,
+                   rows: List[tuple]) -> None:
+        """Park carries off the hot path (restore, rollback, resize):
+        for each ``(stream, row)``, copy ``state``'s row into the
+        stream's home row of the store -- the one it holds, or one no
+        parked stream holds -- and mark it parked. Eager: a gather and
+        a scatter per leaf."""
+        self._ensure_store(lane)
+        free = _free_homes(lane)
+        homes = [lane.parked[sid] if sid in lane.parked else next(free)
+                 for sid, _ in rows]
+        dst = np.asarray(homes, np.int32)
+        src = np.asarray([j for _, j in rows], np.int32)
+        lane.store = self._place_store(jax.tree_util.tree_map(
+            lambda s, a: s.at[dst].set(jnp.asarray(a, s.dtype)[src]),
+            lane.store, state))
+        for (sid, _), row in zip(rows, homes):
+            lane.parked[sid] = row
+
+    def _drop_carries(self, lane: EngineLane) -> None:
+        """Forget every carried state of the lane (its engine is dead
+        or replaced); stateful streams restart cold unless restored."""
+        lane.state = None
+        lane.zero_state = None
+        lane.state_streams = [_FREE] * len(lane.slots)
+        lane.parked.clear()
+        lane.store = None
+        lane.capacity = 0
+
+    def _move_executable(self, lane: EngineLane) -> Callable:
+        """AOT-compile (once per slot count and store capacity) the
+        lane's state-move program (:func:`_move_carries`). Its
+        ``state_in`` keeps the engine's own state layout -- slot-sharded
+        on a mesh -- so the step program takes it as it is. A miss is
+        traced as a ``compile`` span of value 1."""
+        key = (len(lane.slots), lane.capacity)
+        exe = lane.move_exe.get(key)
+        if exe is None:
+            with tracing.span("compile", lane=lane.modality, value=1):
+                exe = lane.move_exe[key] = self._compile_move(lane)
+        return exe
+
+    def _compile_move(self, lane: EngineLane) -> Callable:
+        """The executable :meth:`_move_executable` caches."""
+        state = jax.tree_util.tree_leaves(lane.zero_state)
+        store = jax.tree_util.tree_leaves(lane.store)
+        idx = jax.ShapeDtypeStruct((3, len(lane.slots)), jnp.int32)
+        if self.mesh is None:
+            spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+            move = jax.jit(_move_carries)
+        else:
+            spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=a.sharding)
+            idx = jax.ShapeDtypeStruct(idx.shape, idx.dtype,
+                                       sharding=self._replicated())
+            move = jax.jit(_move_carries, out_shardings=(
+                [a.sharding for a in state], [a.sharding for a in store]))
+        return move.lower([spec(a) for a in state],
+                          [spec(a) for a in store], idx).compile()
 
     # -- scheduling ------------------------------------------------------
 
@@ -2004,10 +2121,11 @@ class StreamEngine:
             if commit is not None:
                 state_commits.append(commit)
         # Commit: every lane dispatched -- pop the served heads and
-        # advance each lane's carried state.
+        # advance each lane's carried state. The parks ran inside the
+        # gather's program, so this span issues no device operation.
         for lane, commit, new_state in state_commits:
-            with tracing.span("state_park", lane=lane.modality) as span:
-                span.value = commit(new_state)
+            with tracing.span("state_park", lane=lane.modality):
+                commit(new_state)
         for rec in ran:
             lane = rec.lane
             rec.items = [None] * len(rec.entries)
@@ -2108,18 +2226,16 @@ class StreamEngine:
         return rec, commit
 
     def _prev_carry(self, lane: EngineLane, heads: List, state_in):
-        """The rollback target quarantine restores: each dispatched
-        stateful stream's pre-window carry, as a lazy device slice of
-        the state that was fed in (recovery only)."""
+        """The rollback target quarantine restores: where each
+        dispatched stateful stream's pre-window carry sits, as ``(state
+        fed in, row)`` (recovery only; nothing is copied unless a
+        rollback happens)."""
         if self.recovery is None or state_in is None:
             return None
-        prev = {}
-        for slot, sid in enumerate(lane.slots):
-            if (sid is not _FREE and sid in lane.stateful
-                    and heads[slot] is not None):
-                prev[sid] = jax.tree_util.tree_map(
-                    lambda a, s=slot: a[s], state_in)
-        return prev
+        return {sid: (state_in, slot)
+                for slot, sid in enumerate(lane.slots)
+                if (sid is not _FREE and sid in lane.stateful
+                    and heads[slot] is not None)}
 
     # -- fusion co-scheduling and the fused megastep ---------------------
 
@@ -2386,7 +2502,8 @@ class StreamEngine:
         lane = rec.lane
         if rec.prev_carry is None or sid not in rec.prev_carry:
             return
-        lane.parked[sid] = rec.prev_carry[sid]
+        state_in, slot = rec.prev_carry[sid]
+        self._park_rows(lane, state_in, [(sid, slot)])
         for j, owner in enumerate(lane.state_streams):
             if owner is not _FREE and owner == sid:
                 lane.state_streams[j] = _FREE

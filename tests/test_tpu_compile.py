@@ -6,7 +6,8 @@ Nothing runs: the TPU compiler lowers each kernel for a chip that is
 described, not attached, and refuses what the chip would refuse
 (unsupported vector ops, unaligned slices, VMEM overcommit) -- the
 failures interpret mode cannot show. Each compiled program must contain
-the Mosaic kernel (``tpu_custom_call``).
+the Mosaic kernel (``tpu_custom_call``). The serving layer's state-move
+program, plain XLA, is compiled at the same widths.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process at a time may load the TPU compiler library,
@@ -141,3 +142,24 @@ def test_served_event_step_keeps_kernels_findable(one_chip,
     assert sorted(kernels.values()) == ["fc_lif_scan", "fc_lif_scan",
                                         "lif_scan", "lif_scan"]
     assert text.count('custom_call_target="tpu_custom_call"') == 4
+
+
+def test_state_move_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The serving layer's state-move program at the Table II widths,
+    for a lane of 32 slots and a carry store of 32 home rows: one
+    gather re-lays the slots and one scatter parks carries, per state
+    leaf."""
+    from repro.core.snn import snn_init_state
+    from repro.serving.stream import _move_carries
+
+    slots = 32
+    planes = [a.shape[1:] for a in
+              jax.tree_util.tree_leaves(snn_init_state(CONFIG, 1))]
+    k = len(planes)
+    text = _compile_text(
+        lambda *a: _move_carries(a[:k], a[k:2 * k], a[-1]),
+        [((slots, *p), jnp.float32) for p in planes]
+        + [((slots + 1, *p), jnp.float32) for p in planes]
+        + [((3, slots), jnp.int32)],
+        one_chip)
+    assert text.count("scatter(") >= k

@@ -112,8 +112,11 @@ def test_slot_changes_record_state_moves(cfg, params):
     assert len(gather) and len(park)
     assert set(cols["parent"][gather]) == {"step"}
     assert set(cols["parent"][park]) == {"step"}
-    assert cols["value"][gather].max() > 0
-    assert cols["value"][park].max() > 0
+    # A step that re-lays the slots issues one compiled program, which
+    # also parks the carries of streams that lost their slot; the other
+    # steps issue none, and the commit issues no device operation.
+    assert set(cols["value"][gather]) == {0, 1}
+    assert set(cols["value"][park]) == {0}
 
 
 def test_identity_fast_path_records_zero(cfg, params):
@@ -121,9 +124,10 @@ def test_identity_fast_path_records_zero(cfg, params):
                      stateful=True)
     gather = _named(cols, "state_gather")
     values = cols["value"][gather]
-    # The first dispatch builds the rows from the zero state; after that
-    # every stream keeps its slot and its carry stays in place.
-    assert len(values) == 4 and values[0] > 0
+    # The first dispatch builds the rows from the zero state in one
+    # program; after that every stream keeps its slot and its carry
+    # stays in place.
+    assert len(values) == 4 and values[0] == 1
     assert list(values[1:]) == [0, 0, 0]
     assert set(cols["value"][_named(cols, "state_park")]) == {0}
 

@@ -1,11 +1,12 @@
 """One run of one cell: set-up, the measured window, the drain.
 
 The timed path is the served one, with nothing bypassed:
-``StreamEngine(engines=[BatchedClosedLoop, FrameTCNEngine])`` built
-from ``EngineConfig(max_streams=slots, pipeline_depth=1, fuse_fc=True,
-recovery=None)``, windows in through ``StreamHandle.submit`` or
-``FusionSession.submit``, results out of ``StreamEngine.step()`` and
-routed to their head by stream id.
+``StreamEngine(engines=...)``, the engines being what the
+configuration's adapter builds (``bench/arch/<arch>.py``, ``build``),
+with ``EngineConfig(max_streams=slots, pipeline_depth=..., fuse_fc=...,
+recovery=None)`` from the configuration, windows in through
+``StreamHandle.submit`` or ``FusionSession.submit``, results out of
+``StreamEngine.step()`` and routed to their head by stream id.
 
 Clock: every time is ``time.perf_counter()``. An open-loop window is
 due when its 300 ms of sensor data has closed (the head's phase plus a
@@ -31,8 +32,9 @@ WINDOW_SPAN = "window"  # host span around the measured window
 
 def served(result) -> Optional[tuple]:
     """What the check needs of one served result: ``(label, pwm, logits,
-    rates)``, rates being the event wing's per-layer firing rates as
-    ``((layer, rate), ...)`` (None for a fused tick). A plain tuple of
+    rates)``, rates being the engine's per-layer firing rates
+    (``breakdown["firing_rates"]``) as ``((layer, rate), ...)``, None
+    where it reports none (a fused tick). A plain tuple of
     numbers and arrays, which Python's garbage collector stops tracking:
     thousands of windows recorded during the measured window must not
     make its full collections longer or more frequent."""
@@ -115,35 +117,15 @@ class Spans:
         return self._ann(name) if self._ann else contextlib.nullcontext()
 
 
-def snn_config(net: dict):
-    from repro.core import SNNConfig
-    from repro.core.lif import LIFParams
-    return SNNConfig(
-        height=net["height"], width=net["width"],
-        in_channels=net["in_channels"], pool0=net["pool0"],
-        conv1_features=net["conv1_features"],
-        conv2_features=net["conv2_features"], hidden=net["hidden"],
-        num_classes=net["num_classes"], time_bins=net["time_bins"],
-        lif=LIFParams(alpha=net["lif_alpha"], v_th=net["lif_v_th"]))
-
-
-def tcn_config(net: dict):
-    from repro.core import TCNConfig
-    return TCNConfig(
-        height=net["height"], width=net["width"],
-        in_channels=net["in_channels"], pool0=net["pool0"],
-        conv1_features=net["conv1_features"],
-        conv2_features=net["conv2_features"], hidden=net["hidden"],
-        num_classes=net["num_classes"], act_threshold=net["act_threshold"])
-
-
 class Server:
-    """The program under test, built for one cell."""
+    """The program under test, built for one cell: the serving layer's
+    settings and mesh here, the engines from the configuration's
+    adapter."""
 
-    def __init__(self, config: dict, chips: int, params, tcn_params):
-        from repro.core import BatchedClosedLoop, EngineConfig, FrameTCNEngine
-        from repro.kernels import lif_scan
+    def __init__(self, config: dict, chips: int, arch, params):
+        from repro.core import EngineConfig
         from repro.serving import StreamEngine
+        self.arch, self.cell_config = arch, config
         self.slots = config["slots_per_chip"] * chips
         mesh = None
         if chips > 1:
@@ -152,33 +134,18 @@ class Server:
         self.window_us = config["window_us"]
         self.config = EngineConfig(
             max_streams=self.slots, pipeline_depth=config["pipeline_depth"],
-            fuse_fc=config["fuse_fc"], recovery=None, mesh=mesh,
+            fuse_fc=config.get("fuse_fc", False), recovery=None, mesh=mesh,
             duration_us=self.window_us)
-        engines = [BatchedClosedLoop.from_config(
-            params, snn_config(config["snn"]), self.config,
-            lif_scan_fn=lif_scan)]
-        self.tcn = config.get("tcn")
-        if self.tcn is not None:
-            engines.append(FrameTCNEngine.from_config(
-                tcn_params, tcn_config(self.tcn), self.config))
-        self.engine = StreamEngine(engines=engines, config=self.config)
-
-    def shape_keys(self, max_events: int) -> Dict[str, tuple]:
-        """The executables this cell's traffic uses: one event key at the
-        pool's event bucket, and the frame key when there is a frame
-        wing. The bucket is the program's rule (``next_pow2``)."""
-        from repro.core import events as ev
-        keys = {"event": (self.slots, ev.next_pow2(max_events),
-                          self.window_us)}
-        if self.tcn is not None:
-            keys["frame"] = (self.slots, self.tcn["height"],
-                             self.tcn["width"], self.window_us)
-        return keys
+        self.engine = StreamEngine(
+            engines=arch.build(config, params, self.config),
+            config=self.config)
 
     def warm(self, pool: tr.Pool) -> Dict[str, tuple]:
-        """Compile the cell's executables (or load them from the
-        persistent cache) before any traffic; returns their keys."""
-        keys = self.shape_keys(max(w.x.shape[0] for w in pool.events))
+        """Compile the executables this cell's traffic uses (the
+        adapter's ``shape_keys``), or load them from the persistent
+        cache, before any traffic; returns their keys by wing."""
+        keys = self.arch.shape_keys(self.cell_config, self.slots, pool,
+                                    self.window_us)
         for modality, key in keys.items():
             self.engine.warmup([key], modality=modality)
         return keys

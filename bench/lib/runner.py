@@ -7,7 +7,7 @@ import sys
 import tempfile
 from typing import Optional
 
-from bench.lib import cells, check, harness, stats, traffic, weights, work
+from bench.lib import cells, check, harness, stats, traffic, work
 from bench.lib.cells import Cell
 
 
@@ -26,6 +26,11 @@ class Run:
     @property
     def chips(self) -> int:
         return self.cell.chips
+
+    @property
+    def arch(self):
+        """The configuration's adapter (``bench/arch/<arch>.py``)."""
+        return self.cell.arch
 
 
 class NoChip(RuntimeError):
@@ -61,14 +66,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     else:
         devs = jax.devices()[:cell.chips]
     marks.append(("devices", time.perf_counter()))
-    config, mix = cell.config, cell.mix
-    snn_params, tcn_params = weights.make(seed, config["snn"],
-                                          config.get("tcn"))
+    config, mix, arch = cell.config, cell.mix, cell.arch
+    params = arch.make_weights(seed, config)
     marks.append(("weights", time.perf_counter()))
-    pool = traffic.make_pool(seed, mix, config["snn"], config.get("tcn"),
+    pool = traffic.make_pool(seed, mix, arch.sensors(config),
                              config["window_us"])
     marks.append(("pool", time.perf_counter()))
-    server = harness.Server(config, cell.chips, snn_params, tcn_params)
+    server = harness.Server(config, cell.chips, arch, params)
     keys = server.warm(pool)
     marks.append(("warm", time.perf_counter()))
     # Where set-up goes, phase by phase (seconds; ``imports`` runs from
@@ -89,8 +93,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             summary = tr_.summarize(tdir, hlo, cell.chips,
                                     harness.WINDOW_SPAN)
             peak = work.peaks(devs[0].device_kind)
-    verdict = check.check(rec, mix, config, seed, pool, snn_params,
-                          tcn_params, control=control)
+    verdict = check.check(rec, mix, config, seed, pool, arch, params,
+                          control=control)
     run = Run(cell=cell, record=rec, trace=summary, peak=peak)
     due = rec.due_in_window()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,

@@ -60,23 +60,28 @@ def submit_lag_ms(rec) -> List[float]:
 def roofline_share(run, kernel: str) -> Optional[float]:
     """% of its roofline that ``kernel`` reached in the traced window:
     its calls' least possible time over their measured device time, per
-    chip. None without a trace or without calls of the kernel."""
+    chip, the work of each call being the configuration adapter's
+    ``kernel_work``. None without a trace, without calls of the kernel,
+    or where the adapter counts no work for it."""
     if run.trace is None or run.peak is None:
         return None
     n, seconds = run.trace.kernel_calls(kernel)
     if not n or seconds <= 0:
         return None
-    calls = work.event_kernels(run.config["snn"],
-                               run.config["slots_per_chip"])[kernel]
+    calls = run.arch.kernel_work(run.config,
+                                 run.config["slots_per_chip"]).get(kernel)
+    if not calls:
+        return None
     least = sum(work.roofline_s(c, run.peak) for c in calls)
     return 100.0 * (n / len(calls)) * least / seconds
 
 
 def step_mfu(run) -> Optional[float]:
-    """% of the chips' peak FLOP/s that the model's own FLOPs per window,
-    at the windows completed per second in the traced window, make up."""
+    """% of the chips' peak FLOP/s that the model's own FLOPs per window
+    (the adapter's ``window_flops``), at the windows completed per second
+    in the traced window, make up."""
     if run.trace is None or run.peak is None:
         return None
     rate = completed_per_s(run.record)
-    return (100.0 * work.window_flops(run.config) * rate
+    return (100.0 * run.arch.window_flops(run.config) * rate
             / (run.chips * run.peak["flops_per_s"]))

@@ -9,16 +9,19 @@ own spans (``step``, ``submit``, ``idle-wait``).
 
 An operation's event is named by its HLO instruction
 (``%fusion.3 = f32[...] fusion(...)``); a module's by its program
-(``jit_run(<fingerprint>)``). The program compiles both wings' steps
-under one name (``jit_run``) and names every Pallas kernel ``_kernel``,
-so names alone cannot tell them apart. The HLO text of each wing's
-compiled step can: a ``jit_run`` execution belongs to the wing whose
-HLO declares the instructions that ran inside it, and each
-``tpu_custom_call``'s serialized kernel body holds the source locations
-it was traced from, among them its kernel file under ``kernels/``
-(``lif_scan.py``, ``fc_lif_scan.py``, ``ternary_matmul.py``). Other
-modules (the serving layer's eager state gathers: ``jit_squeeze``,
-``jit_dynamic_slice``, ...) keep their own names.
+(``jit_run(<fingerprint>)``). Each wing's compiled step is given as HLO
+text, whose ``HloModule <name>`` line names its program: an execution
+is tagged to a wing only when its module carries one of those names.
+Where several wings' steps share a name (the program compiles both the
+event and the frame step as ``jit_run``), the execution goes to the
+wing whose HLO declares the instructions that ran inside it. Every
+other module (the serving layer's state-move program, eager
+operations) stays untagged and keeps its own name, even where its
+instruction names (``fusion.1``, ``copy.33``) also occur in a wing's
+HLO. Each ``tpu_custom_call``'s serialized kernel body holds the source
+locations it was traced from, among them its kernel file under
+``kernels/`` (``lif_scan.py``, ``fc_lif_scan.py``,
+``ternary_matmul.py``), which names the Pallas kernel.
 """
 from __future__ import annotations
 
@@ -33,9 +36,13 @@ from typing import Dict, List, Optional, Tuple
 DEVICE_PLANE = re.compile(r"/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# The name the program's step programs compile under today. The tagging
+# below does not use it: it reads each wing's name from its HLO text.
+# A compile test of the program checks that its event step keeps it.
 STEP_MODULE = "jit_run"
 HOST_SPANS = ("step", "submit", "idle-wait", "window")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_MODULE = re.compile(r"^\s*HloModule\s+([^\s,]+)", re.M)
 _BODY = re.compile(r'"body":"([A-Za-z0-9+/=]*)"')
 _KERNEL_FILE = re.compile(rb"/kernels/(\w+)\.py")
 _NOT_KERNELS = {b"ops", b"backend", b"ref", b"__init__"}
@@ -54,6 +61,13 @@ def hlo_index(text: str) -> Tuple[set, Dict[str, str]]:
         if 'custom_call_target="tpu_custom_call"' in line:
             kernels[m.group(1)] = _kernel_of(line)
     return names, kernels
+
+
+def module_name(text: str) -> Optional[str]:
+    """The program name of an HLO module's text (its ``HloModule``
+    line), which its executions carry in a trace."""
+    m = _MODULE.search(text)
+    return m.group(1) if m else None
 
 
 def _kernel_of(line: str) -> str:
@@ -182,9 +196,12 @@ def _wing_of(op_names: set, index: Dict[str, set]) -> Optional[str]:
 
 def read_chips(data, hlo: Dict[str, str], chips: int) -> List[Chip]:
     """The first ``chips`` TPU planes of a trace, each operation given
-    its wing and, for a Pallas call, its kernel."""
+    its wing and, for a Pallas call, its kernel: only executions of a
+    module that one of the wings' HLO texts names are tagged."""
     index = {w: hlo_index(t) for w, t in hlo.items()}
-    names = {w: i[0] for w, i in index.items()}
+    by_module: Dict[str, Dict[str, set]] = {}
+    for w, t in hlo.items():
+        by_module.setdefault(module_name(t), {})[w] = index[w][0]
     planes = sorted((p for p in data.planes if DEVICE_PLANE.search(p.name)),
                     key=lambda p: int(DEVICE_PLANE.search(p.name).group(1)))
     out = []
@@ -206,8 +223,9 @@ def read_chips(data, hlo: Dict[str, str], chips: int) -> List[Chip]:
                 if ops[i].start >= a:
                     inside.append(ops[i])
                 i += 1
-            wing = (_wing_of({o.name for o in inside}, names)
-                    if module == STEP_MODULE else None)
+            wings = by_module.get(module)
+            wing = (_wing_of({o.name for o in inside}, wings)
+                    if wings else None)
             for o in inside:
                 o.wing, o.module = wing, module
                 if wing is not None:

@@ -140,25 +140,45 @@ class Pool:
     frames: List[Frame]
 
 
-def make_pool(seed: int, mix: dict, snn: dict, tcn: Optional[dict],
-              window_us: int) -> Pool:
+def make_pool(seed: int, mix: dict, sensors: dict, window_us: int) -> Pool:
     """Every window the run will submit, made once at set-up from the
-    seed. Labels cycle through the classes, so every seed gets the same
-    mix of gestures."""
+    seed. ``sensors`` is the configuration adapter's ``sensors(config)``:
+    ``{"event": {"height", "width", "num_classes"}}``, with ``"frame"``
+    alike when the configuration has a frame wing. Labels cycle through
+    the classes, so every seed gets the same mix of gestures."""
     rng = np.random.default_rng(seed)
-    k = snn["num_classes"]
+    dvs = sensors["event"]
+    k = dvs["num_classes"]
     events = [gesture_events(
         rng, i % k, duration_us=window_us, mean_events=mix["mean_events"],
-        height=snn["height"], width=snn["width"], num_classes=k)
+        height=dvs["height"], width=dvs["width"], num_classes=k)
         for i in range(mix["pool_windows"])]
     frames = []
     if mix["fusion"]:
+        cam = sensors["frame"]
         frames = [gesture_frame(
-            rng, i % tcn["num_classes"], duration_us=window_us,
-            height=tcn["height"], width=tcn["width"],
-            num_classes=tcn["num_classes"])
+            rng, i % cam["num_classes"], duration_us=window_us,
+            height=cam["height"], width=cam["width"],
+            num_classes=cam["num_classes"])
             for i in range(mix["pool_frames"])]
     return Pool(events, frames)
+
+
+def pad_events(pool_events: List[Events], idx: List[Optional[int]],
+               n: int):
+    """``(x, y, t, p, valid)``, each ``(len(idx), n)``: the pool's event
+    windows ``idx`` padded to ``n`` events; ``None`` rows are empty."""
+    b = len(idx)
+    x, y, t, p = (np.zeros((b, n), np.int32) for _ in range(4))
+    valid = np.zeros((b, n), bool)
+    for r, i in enumerate(idx):
+        if i is None:
+            continue
+        w = pool_events[i]
+        c = w.x.shape[0]
+        x[r, :c], y[r, :c], t[r, :c], p[r, :c] = w.x, w.y, w.t, w.p
+        valid[r, :c] = True
+    return x, y, t, p, valid
 
 
 def phases_ms(seed: int, heads: int, period_ms: float) -> np.ndarray:

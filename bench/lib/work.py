@@ -1,5 +1,8 @@
-"""Operations and bytes the algorithm needs, from the configuration's
-sizes, and the table of device peaks.
+"""Operations and bytes of each served Pallas kernel's call, and the
+table of device peaks. Which calls a step makes, at which sizes, and a
+window's model FLOPs are the configuration adapter's
+(``bench/arch/<arch>.py``: ``kernel_work``, ``window_flops``), built
+from the functions here.
 
 Counts are of the algorithm at its unpadded shapes, not of what a kernel
 happens to move: a padded or re-read operand costs the kernel time
@@ -20,7 +23,7 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List
+from typing import Dict
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 PEAKS = os.path.join(os.path.dirname(HERE), "peaks.json")
@@ -38,11 +41,6 @@ def peaks(device_kind: str, path: str = PEAKS) -> Dict[str, float]:
     return table[device_kind]
 
 
-def _sizes(net: dict):
-    h0, w0 = net["height"] // net["pool0"], net["width"] // net["pool0"]
-    return h0, w0, (h0 // 4) * (w0 // 4) * net["conv2_features"]
-
-
 def lif_scan(t: int, b: int, neurons: int) -> Dict[str, float]:
     """One LIF scan over (T, B, neurons) currents: currents and the
     initial membrane read, spikes and the final membrane written."""
@@ -58,51 +56,6 @@ def fc_lif_scan(t: int, b: int, k: int, n: int) -> Dict[str, float]:
     return {"ops": 2 * t * b * k * n + LIF_OPS * t * b * n,
             "read": F32 * (t * b * k + k * n + b * n),
             "write": F32 * (t * b * n + b * n)}
-
-
-def event_kernels(net: dict, b: int) -> Dict[str, List[Dict[str, float]]]:
-    """Per step of ``b`` slots: each call of the served event wing's
-    Pallas kernels, by kernel (conv1 and conv2 through ``lif_scan``,
-    fc1 and fc2 through ``fc_lif_scan``)."""
-    t = net["time_bins"]
-    h0, w0, flat = _sizes(net)
-    return {
-        "lif_scan": [
-            lif_scan(t, b, h0 * w0 * net["conv1_features"]),
-            lif_scan(t, b, (h0 // 2) * (w0 // 2) * net["conv2_features"])],
-        "fc_lif_scan": [
-            fc_lif_scan(t, b, flat, net["hidden"]),
-            fc_lif_scan(t, b, net["hidden"], net["num_classes"])]}
-
-
-def snn_flops(net: dict) -> float:
-    """Model FLOPs of one event window: the SCNN's convolutions and fully
-    connected layers, dense, over T steps."""
-    t = net["time_bins"]
-    h0, w0, flat = _sizes(net)
-    conv1 = 2 * h0 * w0 * net["conv1_features"] * 9 * net["in_channels"]
-    conv2 = (2 * (h0 // 2) * (w0 // 2) * net["conv2_features"] * 9
-             * net["conv1_features"])
-    fc = 2 * flat * net["hidden"] + 2 * net["hidden"] * net["num_classes"]
-    return float(t * (conv1 + conv2 + fc))
-
-
-def tcn_flops(net: dict) -> float:
-    """Model FLOPs of one frame through the CUTIE network."""
-    h0, w0, flat = _sizes(net)
-    conv1 = 2 * h0 * w0 * net["conv1_features"] * 9 * net["in_channels"]
-    conv2 = (2 * (h0 // 2) * (w0 // 2) * net["conv2_features"] * 9
-             * net["conv1_features"])
-    return float(conv1 + conv2 + 2 * flat * net["hidden"]
-                 + 2 * net["hidden"] * net["num_classes"])
-
-
-def window_flops(config: dict) -> float:
-    """Model FLOPs of one served window (a fused tick: both wings)."""
-    f = snn_flops(config["snn"])
-    if "tcn" in config:
-        f += tcn_flops(config["tcn"])
-    return f
 
 
 def _times(work: Dict[str, float], peak: Dict[str, float]):

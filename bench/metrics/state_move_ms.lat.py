@@ -1,6 +1,6 @@
 """Mean host time per StreamEngine.step() started in the measured window
-that the program spent moving carried state between slots (its
-``state_gather`` and ``state_park`` spans)."""
+that the program spent moving carried state between slots through its
+state-move programs (its ``state_gather`` and ``state_park`` spans)."""
 from bench.lib import program_spans
 
 

@@ -1,6 +1,7 @@
-"""Mean number of eager array operations per StreamEngine.step() started
-in the measured window that the program issued to move carried state
-(the summed values of its ``state_gather`` and ``state_park`` spans)."""
+"""Mean number of state-move programs per StreamEngine.step() started in
+the measured window: the summed values of the program's
+``state_gather`` and ``state_park`` spans. One compiled program moves a
+step's carried state, so this is the share of steps that move it."""
 from bench.lib import program_spans
 
 

@@ -100,8 +100,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
-    from bench.lib import (cells, harness, runner, trace, traffic, weights,
-                           work)
+    from bench.lib import cells, harness, runner, trace, traffic, work
     from repro.compile_cache import enable_compile_cache
     cell = cells.cell(args.workload)
     try:
@@ -110,11 +109,11 @@ def main(argv=None) -> int:
         print(f"bench/span_report.py: {e}", file=sys.stderr)
         return 1
     enable_compile_cache()
-    config, mix = cell.config, cell.mix
-    snn, tcn = weights.make(args.seed, config["snn"], config.get("tcn"))
-    pool = traffic.make_pool(args.seed, mix, config["snn"],
-                             config.get("tcn"), config["window_us"])
-    server = harness.Server(config, cell.chips, snn, tcn)
+    config, mix, arch = cell.config, cell.mix, cell.arch
+    pool = traffic.make_pool(args.seed, mix, arch.sensors(config),
+                             config["window_us"])
+    server = harness.Server(config, cell.chips, arch,
+                            arch.make_weights(args.seed, config))
     hlo = server.hlo_texts(server.warm(pool))
     with tempfile.TemporaryDirectory(prefix="bench-trace-") as tdir:
         rec = harness.serve(server, mix, mix["heads"] * cell.chips,

@@ -1,10 +1,13 @@
-"""The benchmark's cells cut to a size the CPU runs in seconds: the
-``SMOKE``/``TCN_SMOKE`` widths of ``repro.configs.colibries``, 4 slots
-per chip, small windows and short pools. For rehearsals and tests only;
-the cells themselves run at the published widths."""
+"""The benchmark's SCNN cells (configurations with ``"arch": "scnn"``)
+cut to a size the CPU runs in seconds: the ``SMOKE``/``TCN_SMOKE``
+widths of ``repro.configs.colibries``, 4 slots per chip, small windows
+and short pools. For rehearsals and tests only; the cells themselves
+run at the published widths. A configuration of another network brings
+its own tests."""
 from __future__ import annotations
 
 import copy
+import dataclasses
 
 from bench.lib import cells
 
@@ -16,9 +19,16 @@ TCN = {"height": 32, "width": 32, "in_channels": 1, "pool0": 4,
        "num_classes": 11}
 
 
+SPEC = cells.load_spec()
+CELLS = [w["name"] for w in SPEC["workloads"]
+         if cells.cell(w["name"], SPEC).config["arch"] == "scnn"]
+
+
 def cell(name: str, heads: int = 0) -> cells.Cell:
     """The cell ``name`` at smoke size (``heads`` per chip if given)."""
-    c = copy.deepcopy(cells.cell(name))
+    c = cells.cell(name)
+    c = dataclasses.replace(c, config=copy.deepcopy(c.config),
+                            mix=copy.deepcopy(c.mix))
     c.config["snn"].update(SNN)
     if "tcn" in c.config:
         c.config["tcn"].update(TCN)

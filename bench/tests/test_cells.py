@@ -24,7 +24,11 @@ def test_spec_names_and_files():
     for c in SPEC["configs"]:
         assert c["file"].startswith("bench/")
         with open(os.path.join(cells.ROOT, c["file"])) as f:
-            assert json.load(f)["snn"]["time_bins"] == 16
+            config = json.load(f)
+        assert os.path.isfile(os.path.join(cells.ROOT, "bench", "arch",
+                                           f"{config['arch']}.py"))
+        if config["arch"] == "scnn":
+            assert config["snn"]["time_bins"] == 16
     for w in SPEC["workloads"]:
         limits = traffic.load(w["traffic"])["limits"]
         assert limits and set(limits) <= set(check.NAMES)
@@ -43,7 +47,7 @@ def test_every_cell_reports_setup_another_end_to_end_and_a_layer(name):
         assert m["moves"] in e2e
 
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", smoke.CELLS)
 def test_cell_runs_and_is_correct_at_smoke_size(name):
     c = smoke.cell(name)
     out = runner.run_cell(c, 2 ** 31 + 77, 1.0, False, time.perf_counter(),

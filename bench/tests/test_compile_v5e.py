@@ -12,7 +12,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from bench.lib import cells, harness
+from bench.lib import cells
 
 EVENTS = 65536
 
@@ -49,8 +49,9 @@ def compiled_kernels(monkeypatch):
 def _engine():
     from repro.core import BatchedClosedLoop
     from repro.kernels import lif_scan
-    config = cells.cell("scnn_paper_saturated").config
-    cfg = harness.snn_config(config["snn"])
+    cell = cells.cell("scnn_paper_saturated")
+    config = cell.config
+    cfg = cell.arch.snn_config(config["snn"])
     from repro.core import init_snn
     params = jax.eval_shape(lambda: init_snn(jax.random.PRNGKey(0), cfg))
     eng = BatchedClosedLoop(params, cfg, lif_scan_fn=lif_scan, fuse_fc=True)

@@ -10,7 +10,7 @@ in PERF.md)."""
 import numpy as np
 import pytest
 
-from bench.lib import cells, check, harness, traffic, weights
+from bench.lib import cells, check, harness, traffic
 
 
 def _sample(n_heads, n_windows, pool):
@@ -27,18 +27,17 @@ def _sample(n_heads, n_windows, pool):
 ])
 def test_control_fails_the_configured_limits(name, heads, windows):
     cell = cells.cell(name)
-    config = cell.config
+    config, arch = cell.config, cell.arch
     mix = dict(cell.mix, pool_windows=64, pool_frames=4)
     seed = 2 ** 31 + 5
-    snn_params, tcn_params = weights.make(seed, config["snn"],
-                                          config.get("tcn"))
-    pool = traffic.make_pool(seed, mix, config["snn"], config.get("tcn"),
+    params = arch.make_weights(seed, config)
+    pool = traffic.make_pool(seed, mix, arch.sensors(config),
                              config["window_us"])
     sample = _sample(heads, windows, pool)
-    want = check.reference_rows(snn_params, tcn_params, pool, sample, config)
-    again = check.reference_rows(snn_params, tcn_params, pool, sample, config)
-    control = check.reference_rows(snn_params, tcn_params, pool, sample,
-                                   config, precision="high")
+    want = arch.reference_rows(params, pool, sample, config)
+    again = arch.reference_rows(params, pool, sample, config)
+    control = arch.reference_rows(params, pool, sample, config,
+                                  precision="high")
     limits = cell.mix["limits"]
     assert check.judge(check.readings(again, want), limits)["ok"]
     verdict = check.judge(check.readings(control, want), limits)

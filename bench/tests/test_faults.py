@@ -5,8 +5,10 @@ size, past the harness's look for a chip; the comparison with the
 reference has to catch it: an answer altered where it is produced, half
 of each batch left out, the carried state never advanced (stateful
 cells), and, for fused heads, the two wings fused with other weights
-than the configuration's or paired one tick apart. No cell runs on
-several chips, so no exchange between chips can be left out."""
+than the configuration's or paired one tick apart. The four-chip cell
+shards the slot axis of a step that holds no collective (each chip
+serves its own slots), so there is no exchange between chips to leave
+out; its faults run on four virtual CPU devices."""
 import dataclasses
 import time
 
@@ -16,7 +18,7 @@ import pytest
 from bench.lib import cells, runner
 from bench.tests import smoke
 
-CELLS = [w["name"] for w in cells.load_spec()["workloads"]]
+CELLS = smoke.CELLS
 
 
 def _run(name):

@@ -13,8 +13,8 @@ def mix():
 
 @pytest.fixture(scope="module")
 def nets():
-    c = smoke.cell("fusion_uav_p80").config
-    return c["snn"], c["tcn"], c["window_us"]
+    c = smoke.cell("fusion_uav_p80")
+    return c.arch.sensors(c.config), c.config["window_us"]
 
 
 def _arrays(pool):
@@ -42,10 +42,10 @@ def test_every_seed_gets_the_same_labels_and_shapes(mix, nets):
 
 
 def test_events_stay_on_the_sensor_and_in_the_window(mix, nets):
-    snn, _, window_us = nets
+    dvs, window_us = nets[0]["event"], nets[1]
     for w in tr.make_pool(3, mix, *nets).events:
-        assert w.x.min() >= 0 and w.x.max() < snn["width"]
-        assert w.y.min() >= 0 and w.y.max() < snn["height"]
+        assert w.x.min() >= 0 and w.x.max() < dvs["width"]
+        assert w.y.min() >= 0 and w.y.max() < dvs["height"]
         assert w.t.min() >= 0 and w.t.max() < window_us
         assert set(np.unique(w.p)) <= {0, 1}
         assert np.all(np.diff(w.t) >= 0)

@@ -147,12 +147,12 @@ def test_idle_share_on_the_trace_clock(ring):
 
 
 def test_host_span_metrics_read_a_smoke_run():
-    from bench.lib import cells, traffic, weights
+    from bench.lib import cells, traffic
     cell = smoke.cell("scnn_tracking_p80")
-    snn, _ = weights.make(5, cell.config["snn"])
-    pool = traffic.make_pool(5, cell.mix, cell.config["snn"], None,
-                             cell.config["window_us"])
-    server = harness.Server(cell.config, 1, snn, None)
+    config, arch = cell.config, cell.arch
+    pool = traffic.make_pool(5, cell.mix, arch.sensors(config),
+                             config["window_us"])
+    server = harness.Server(config, 1, arch, arch.make_weights(5, config))
     server.warm(pool)
     rec = harness.serve(server, cell.mix, cell.mix["heads"], 5, pool, 1.0,
                         time.perf_counter(), 1)

@@ -6,11 +6,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bench.lib import harness, traffic, weights
+from bench.lib import traffic
 from bench.reference import cutie, scnn
 from bench.tests import smoke
 
 B = 3
+
+
+ARCH = smoke.cell("fusion_uav_p80").arch
 
 
 @pytest.fixture(scope="module")
@@ -20,8 +23,9 @@ def nets():
 
 
 @pytest.fixture(scope="module")
-def params(nets):
-    return weights.make(2 ** 32 + 9, nets[0], nets[1])
+def params():
+    p = ARCH.make_weights(2 ** 32 + 9, smoke.cell("fusion_uav_p80").config)
+    return p["snn"], p["tcn"]
 
 
 def _events(nets, seed=4):
@@ -55,7 +59,7 @@ def test_voxelize_matches_the_program(nets):
 
 def _program(params, vox, net, state=None):
     from repro.core import snn_apply
-    out = snn_apply(params, vox, harness.snn_config(net),
+    out = snn_apply(params, vox, ARCH.snn_config(net),
                     mode="layer_serial", state=state)
     counts = jnp.stack([out["spikes"][n].sum(axis=tuple(
         a for a in range(out["spikes"][n].ndim) if a != 1))
@@ -89,7 +93,7 @@ def test_cutie_matches_the_program(nets, params):
         width=tnet["width"], num_classes=11).pixels for i in range(B)])
     want = tcn_apply(pack_tcn(params[1]),
                      fr.normalize_frames(pixels[..., None]),
-                     harness.tcn_config(tnet))["logits"]
+                     ARCH.tcn_config(tnet))["logits"]
     got = cutie.forward(params[1], pixels, tnet)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 

@@ -5,6 +5,7 @@ fused event+frame heads served through one ``StreamEngine`` at the
 Table II widths, and the HLO text of the two compiled steps. The trace
 holds no benchmark spans, so the cut to the measured window is tested
 on a window span laid over the middle of its device operations."""
+import base64
 import gzip
 import os
 import types
@@ -92,7 +93,80 @@ def test_breakdown_is_short_and_named(summary):
 def test_roofline_shares_stay_under_100(summary):
     cell = cells.cell("scnn_paper_saturated")
     run = types.SimpleNamespace(trace=summary, config=cell.config,
-                                chips=1, peak=work.peaks("TPU v5 lite"))
+                                arch=cell.arch, chips=1,
+                                peak=work.peaks("TPU v5 lite"))
     for kernel in ("lif_scan", "fc_lif_scan"):
         share = stats.roofline_share(run, kernel)
         assert 0 < share < 100, (kernel, share)
+
+
+def test_module_name_is_read_from_the_hlo(hlo):
+    assert trace.module_name(hlo["event"]) == "jit_run"
+    assert trace.module_name(hlo["frame"]) == "jit_run"
+    assert trace.module_name("HloModule jit_step.3, is_scheduled=true\n") \
+        == "jit_step.3"
+
+
+def _kernel_line(name: str, kernel: str) -> str:
+    body = base64.b64encode(f"loc(/src/repro/kernels/{kernel}.py:7)".encode())
+    return (f'  %{name} = f32[4]{{0}} custom-call(f32[4]{{0}} %p), '
+            f'custom_call_target="tpu_custom_call", '
+            f'backend_config={{"body":"{body.decode()}"}}')
+
+
+def _hlo(module: str, lines) -> str:
+    return "\n".join([f"HloModule {module}, is_scheduled=true", "",
+                      "ENTRY %main (p: f32[4]) -> f32[4] {",
+                      "  %p = f32[4]{0} parameter(0)", *lines, "}"])
+
+
+def _event(name: str, start: int, end: int):
+    return types.SimpleNamespace(name=name, start_ns=start,
+                                 duration_ns=end - start)
+
+
+def _plane(modules, ops):
+    line = lambda n, ev: types.SimpleNamespace(name=n, events=ev)
+    return types.SimpleNamespace(name="/device:TPU:0", lines=[
+        line(trace.MODULES_LINE, [_event(*m) for m in modules]),
+        line(trace.OPS_LINE, [_event(f"%{n} = f32[4]{{0}} op()", a, b)
+                              for n, a, b in ops])])
+
+
+@pytest.mark.parametrize("event_module,frame_module", [
+    ("jit_event_step", "jit_frame_step"),    # each wing named its own way
+    ("jit_step", "jit_step"),                 # one name, told by the HLO
+])
+def test_wings_are_tagged_by_their_own_module_name(event_module,
+                                                   frame_module):
+    hlo = {"event": _hlo(event_module, [
+               "  %fusion.1 = f32[4]{0} fusion(f32[4]{0} %p)",
+               _kernel_line("lif_scan.2", "lif_scan"),
+               "  ROOT %copy.33 = f32[4]{0} copy(f32[4]{0} %p)"]),
+           "frame": _hlo(frame_module, [
+               "  %convolution.5 = f32[4]{0} convolution(f32[4]{0} %p)",
+               "  ROOT %copy.34 = f32[4]{0} copy(f32[4]{0} %p)"])}
+    data = types.SimpleNamespace(planes=[_plane(
+        modules=[(f"{event_module}(11)", 0, 100),
+                 ("jit__move_carries(22)", 200, 300),
+                 (f"{frame_module}(33)", 400, 500),
+                 ("jit_run(44)", 600, 700)],
+        ops=[("fusion.1", 0, 40), ("lif_scan.2", 40, 90),
+             ("fusion.1", 200, 250), ("copy.33", 250, 290),
+             ("convolution.5", 400, 450), ("copy.34", 450, 480),
+             ("lif_scan.2", 600, 650)])])
+    chip = trace.read_chips(data, hlo, 1)[0]
+    assert [w for _, _, w in chip.modules] == ["event", None, "frame", None]
+    by_start = {o.start: o for o in chip.ops}
+    assert by_start[0].wing == by_start[40].wing == "event"
+    assert by_start[40].kernel == "lif_scan"
+    # Another program whose instruction names recur in the event step's
+    # HLO, and a module under a name no wing has, stay untagged.
+    for t in (200, 250, 600):
+        assert by_start[t].wing is None and by_start[t].kernel is None
+    assert by_start[200].module == "jit__move_carries"
+    assert by_start[600].module == "jit_run"
+    assert by_start[400].wing == "frame"
+    summary = trace.Summary(window_s=1e-6, chips=[chip], host_spans=[])
+    assert summary.module_ms("event") == pytest.approx(90 / 1e6)
+    assert summary.kernel_calls("lif_scan") == (1.0, 50 / 1e9)

@@ -23,13 +23,14 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 __all__ = [
     "LIFParams",
+    "LIFLayer",
     "spike_surrogate",
     "lif_step",
     "lif_scan_reference",
@@ -43,6 +44,21 @@ class LIFParams:
     alpha: float = 0.875     # membrane leak per step (SNE uses 1 - 2^-k leaks)
     v_th: float = 0.5        # firing threshold
     surrogate_width: float = 2.0  # 'a' in the STBP rectangular surrogate
+
+
+class LIFLayer(NamedTuple):
+    """One LIF layer of an event network, as the serving engine accounts
+    it: its output volume ``shape`` (H, W, C) for the tiling planner,
+    ``fanout`` -- the synapses each of its input spikes drives -- and
+    ``source``, the layer whose spikes feed it (``None``: the events).
+    Each network config's ``lif_layers()`` lists them in execution
+    order; they also name the carried-state planes and the per-stream
+    firing rates."""
+
+    name: str
+    shape: Tuple[int, int, int]
+    fanout: float
+    source: Optional[str]
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(2,))

@@ -48,7 +48,6 @@ from repro.core import events as ev
 from repro.core._api import (EngineConfig, suppress_api_deprecations,
                              warn_deprecated_call)
 from repro.core.energy import KrakenModel, NOMINAL
-from repro.core.snn import SNNConfig, snn_apply, snn_init_state, snn_logits
 from repro.core.tiling import SNE_NEURON_CAPACITY, plan_network
 
 __all__ = ["ClosedLoopResult", "BatchedClosedLoop", "ClosedLoopPipeline",
@@ -229,24 +228,34 @@ class BatchedClosedLoop:
     share a bin width (pass it at construction to pin it, or leave
     ``None`` to latch it from the first validated window).
 
-    ``fuse_fc=True`` routes the fc1/fc2 layers through the fused
-    synapse+LIF Pallas kernel (``kernels/fc_lif_scan.py``): their
-    synaptic-current tensors never round-trip HBM, with bitwise-identical
-    results to the unfused path.
+    The network is the config object's: ``cfg`` (an
+    :class:`~repro.core.snn.SNNConfig` for the Table II SCNN, a
+    :class:`~repro.core.spikformer.SpikformerConfig` for Spikformer)
+    supplies the sensor geometry and ``time_bins`` the voxelizer reads,
+    ``init_state(B)``, ``apply(params, vox, ...)`` -- returning logits,
+    per-stream firing rates and the final state -- and ``lif_layers()``,
+    the LIF layer table the tiling plans and the per-stream accounting
+    read.
 
-    Carried state (stateful streaming): the SNN is stateful across the
-    control loop, and this engine exposes that as a first-class slot-major
-    pytree -- one (B, ...) membrane plane per LIF layer. ``init_state(B)``
-    makes the zero (cold-start) state; ``infer(batch, state)`` returns
-    ``(results, new_state)``, and feeding ``new_state`` back chains the
-    windows bitwise-exactly into one uninterrupted scan (the ``s0 = v0 >=
-    v_th`` contract from ``core/lif.py``). The state stays a device
-    pytree end to end: ``infer_dispatch(batch, state)`` returns the new
-    state as jax async-dispatch futures, so a pipelined caller threads
-    membranes from step to step without any host round-trip. Calls
-    without ``state`` run the same executable from the zero state and
-    drop the final state -- the legacy stateless behaviour, bitwise
-    unchanged.
+    ``fuse_fc=True`` routes the fully-connected layers (the SCNN's
+    fc1/fc2, Spikformer's token linears) through the fused synapse+LIF
+    Pallas kernel (``kernels/fc_lif_scan.py``): their synaptic-current
+    tensors never round-trip HBM, with bitwise-identical results to the
+    unfused path.
+
+    Carried state (stateful streaming): the network is stateful across
+    the control loop, and this engine exposes that as a first-class
+    slot-major pytree -- one (B, ...) membrane plane per LIF layer.
+    ``init_state(B)`` makes the zero (cold-start) state; ``infer(batch,
+    state)`` returns ``(results, new_state)``, and feeding ``new_state``
+    back chains the windows bitwise-exactly into one uninterrupted scan
+    (the ``s0 = v0 >= v_th`` contract from ``core/lif.py``). The state
+    stays a device pytree end to end: ``infer_dispatch(batch, state)``
+    returns the new state as jax async-dispatch futures, so a pipelined
+    caller threads membranes from step to step without any host
+    round-trip. Calls without ``state`` run the same executable from the
+    zero state and drop the final state -- the legacy stateless
+    behaviour, bitwise unchanged.
     """
 
     modality = "event"
@@ -254,7 +263,7 @@ class BatchedClosedLoop:
     def __init__(
         self,
         params,
-        cfg: SNNConfig,
+        cfg,
         *,
         model: Optional[KrakenModel] = None,
         lif_scan_fn: Optional[Callable] = None,
@@ -270,20 +279,13 @@ class BatchedClosedLoop:
         self.window_ms = window_ms
         self.duration_us = duration_us
         self.fuse_fc = fuse_fc
-        sizes = cfg.spatial_sizes()
-        # SNE executes conv1/conv2/fc1/fc2; tile plans sized by each layer's
+        # SNE executes the LIF layers; tile plans sized by each layer's
         # output volume against SNE's neuron capacity.
-        self.plans = plan_network(
-            [("conv1", sizes["conv1"]), ("conv2", sizes["conv2"]),
-             ("fc1", sizes["fc1"]), ("fc2", sizes["fc2"])],
-            SNE_NEURON_CAPACITY,
-        )
-        self.fanouts = (
-            9.0 * cfg.conv1_features,         # 3x3 kernel into conv1 features
-            9.0 * cfg.conv2_features,
-            float(cfg.hidden),
-            float(cfg.num_classes),
-        )
+        self.layers = cfg.lif_layers()
+        self.plans = plan_network([(l.name, l.shape) for l in self.layers],
+                                  SNE_NEURON_CAPACITY)
+        self.fanouts = tuple(l.fanout for l in self.layers)
+        self._volumes = {l.name: float(np.prod(l.shape)) for l in self.layers}
         _check_scan_fn(lif_scan_fn)
         self._lif_scan_fn = lif_scan_fn
         # Explicit executable cache: shape_key -> AOT-compiled callable.
@@ -295,7 +297,7 @@ class BatchedClosedLoop:
             self.attach_mesh(mesh)
 
     @classmethod
-    def from_config(cls, params, cfg: SNNConfig, config: EngineConfig, *,
+    def from_config(cls, params, cfg, config: EngineConfig, *,
                     model: Optional[KrakenModel] = None,
                     lif_scan_fn: Optional[Callable] = None):
         """Construct from the unified :class:`EngineConfig` surface (the
@@ -342,7 +344,7 @@ class BatchedClosedLoop:
         """The zero carried-state pytree for ``batch_size`` slots.
 
         Slot-major: one (batch_size, ...) f32 membrane plane per LIF
-        layer (see :func:`repro.core.snn.snn_init_state`). Zero membrane
+        layer (the network config's ``init_state``). Zero membrane
         is the cold-start condition, so a window inferred from
         ``init_state`` is bitwise identical to a stateless call.
 
@@ -352,7 +354,7 @@ class BatchedClosedLoop:
         stay plain host-side arrays -- they are only ever sliced and
         spliced, never inferred.
         """
-        state = snn_init_state(self.cfg, batch_size)
+        state = self.cfg.init_state(batch_size)
         if self.mesh is not None:
             _, n = _mesh_slot_info(self.mesh)
             if batch_size % n == 0:
@@ -408,13 +410,11 @@ class BatchedClosedLoop:
                     time_bins=cfg.time_bins, height=cfg.height,
                     width=cfg.width,
                 )
-            out = snn_apply(params, vox, cfg, mode="layer_serial",
-                            lif_scan_fn=scan, fuse_fc=fuse, state=state)
+            logits, rates, new_state = cfg.apply(
+                params, vox, lif_scan_fn=scan, fuse_fc=fuse, state=state)
             with jax.named_scope("readout"):
-                logits = snn_logits(out, cfg) * 10.0
                 return (jnp.argmax(logits, -1), pwm_from_logits(logits),
-                        logits, out["firing_rates_per_stream"],
-                        out["state"])
+                        logits, rates, new_state)
 
         return run
 
@@ -545,17 +545,15 @@ class BatchedClosedLoop:
 
     def _account(self, num_events: int,
                  rates: Dict[str, float]) -> Dict[str, Any]:
-        """Kraken latency/energy for one stream's window (pure float math)."""
-        cfg = self.cfg
-        t = cfg.time_bins
-        sizes = cfg.spatial_sizes()
-        vol = lambda s: float(np.prod(sizes[s]))
-        layer_in_spikes = (
-            float(num_events),                        # into conv1
-            rates["conv1"] * vol("conv1") * t,        # into conv2
-            rates["conv2"] * vol("conv2") * t,        # into fc1
-            rates["fc1"] * vol("fc1") * t,            # into fc2
-        )
+        """Kraken latency/energy for one stream's window (pure float math).
+
+        The spikes into each LIF layer are the events (first layer) or
+        its source layer's spikes: firing rate x neurons x time bins."""
+        t = self.cfg.time_bins
+        layer_in_spikes = tuple(
+            float(num_events) if l.source is None
+            else rates[l.source] * self._volumes[l.source] * t
+            for l in self.layers)
         acct = self.model.closed_loop(
             events=float(num_events),
             layer_in_spikes=layer_in_spikes,
@@ -714,7 +712,7 @@ class ClosedLoopPipeline:
     def __init__(
         self,
         params,
-        cfg: SNNConfig,
+        cfg,
         *,
         model: Optional[KrakenModel] = None,
         lif_scan_fn: Optional[Callable] = None,

@@ -36,8 +36,8 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.lif import (LIFParams, lif_scan_reference, lif_step,
-                            spike_surrogate)
+from repro.core.lif import (LIFLayer, LIFParams, lif_scan_reference,
+                            lif_step, spike_surrogate)
 
 __all__ = ["SNNConfig", "init_snn", "snn_init_state", "snn_apply",
            "snn_logits", "snn_loss", "SNN_STATE_LAYERS"]
@@ -93,6 +93,37 @@ class SNNConfig:
             "fc1": (1, 1, self.hidden),
             "fc2": (1, 1, self.num_classes),
         }
+
+    # -- the event-network interface the serving engine drives ----------
+    # (``BatchedClosedLoop`` takes its network from this config object.)
+
+    def lif_layers(self) -> Tuple[LIFLayer, ...]:
+        """The LIF layers in execution order (:class:`LIFLayer`)."""
+        sizes = self.spatial_sizes()
+        return (
+            LIFLayer("conv1", sizes["conv1"], 9.0 * self.conv1_features,
+                     None),          # 3x3 kernel into conv1 features
+            LIFLayer("conv2", sizes["conv2"], 9.0 * self.conv2_features,
+                     "conv1"),
+            LIFLayer("fc1", sizes["fc1"], float(self.hidden), "conv2"),
+            LIFLayer("fc2", sizes["fc2"], float(self.num_classes), "fc1"),
+        )
+
+    def init_state(self, batch_size: int) -> Dict[str, jnp.ndarray]:
+        """The zero carried state; see :func:`snn_init_state`."""
+        return snn_init_state(self, batch_size)
+
+    def apply(self, params: Params, vox: jnp.ndarray, *, lif_scan_fn=None,
+              fuse_fc: bool = False, state=None):
+        """The served forward pass: ``snn_apply`` in ``layer_serial``
+        mode, then the readout. Returns ``(logits, per-stream firing
+        rates, final state)``."""
+        out = snn_apply(params, vox, self, mode="layer_serial",
+                        lif_scan_fn=lif_scan_fn, fuse_fc=fuse_fc,
+                        state=state)
+        with jax.named_scope("readout"):
+            logits = snn_logits(out, self) * 10.0
+        return logits, out["firing_rates_per_stream"], out["state"]
 
 
 def init_snn(rng: jax.Array, cfg: SNNConfig, dtype=jnp.float32) -> Params:

@@ -119,22 +119,23 @@ def lif_scan_batched(
 # policy as lif_scan; forward values are bit-identical to unfused).
 # ----------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fc_lif_scan_cv(spikes, w, v0, p: LIFParams):
-    return fc_lif_scan_pallas(spikes, w, p, v0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _fc_lif_scan_cv(spikes, w, v0, bias, p: LIFParams):
+    return fc_lif_scan_pallas(spikes, w, p, v0, bias=bias)
 
 
-def _fc_fwd(spikes, w, v0, p):
-    return _fc_lif_scan_cv(spikes, w, v0, p), (spikes, w, v0)
+def _fc_fwd(spikes, w, v0, bias, p):
+    return _fc_lif_scan_cv(spikes, w, v0, bias, p), (spikes, w, v0, bias)
 
 
 def _fc_bwd(p, res, cotangents):
-    spikes, w, v0 = res
+    spikes, w, v0, bias = res
 
-    def ref(s, w_, v):
-        return lif_scan_reference(jnp.matmul(s, w_), p, v)
+    def ref(s, w_, v, b):
+        cur = jnp.matmul(s, w_)
+        return lif_scan_reference(cur if b is None else cur + b, p, v)
 
-    _, vjp = jax.vjp(ref, spikes, w, v0)
+    _, vjp = jax.vjp(ref, spikes, w, v0, bias)
     return vjp(cotangents)
 
 
@@ -146,22 +147,24 @@ def fc_lif_scan(
     w: jnp.ndarray,
     p: LIFParams = LIFParams(),
     v0: jnp.ndarray | None = None,
+    bias: jnp.ndarray | None = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Fused ``spikes @ w`` + LIF scan -> (out_spikes, v_final).
+    """Fused ``spikes @ w (+ bias)`` + LIF scan -> (out_spikes, v_final).
 
-    Drop-in for ``lif_scan_reference(spikes @ w, p, v0)`` (bitwise-equal
-    forward, same STBP surrogate gradients) with the synaptic matmul and
-    the temporal scan fused into one Pallas launch: weights and membrane
-    stay VMEM-resident, the (T, B, N) current tensor never exists in HBM
-    (see ``kernels/fc_lif_scan.py``).
+    Drop-in for ``lif_scan_reference(spikes @ w + bias, p, v0)``
+    (bitwise-equal forward, same STBP surrogate gradients) with the
+    synaptic matmul and the temporal scan fused into one Pallas launch:
+    weights and membrane stay VMEM-resident, the (T, B, N) current tensor
+    never exists in HBM (see ``kernels/fc_lif_scan.py``).
 
-    ``spikes``: (T, B, K) or (T, K); ``w``: (K, N); ``v0``: (B, N)/(N,).
+    ``spikes``: (T, B, K) or (T, K); ``w``: (K, N); ``v0``: (B, N)/(N,);
+    ``bias``: (N,) or None.
     """
     if v0 is None:
         shape = ((spikes.shape[1], w.shape[1]) if spikes.ndim == 3
                  else (w.shape[1],))
         v0 = jnp.zeros(shape, spikes.dtype)
-    return _fc_lif_scan_cv(spikes, w, v0, p)
+    return _fc_lif_scan_cv(spikes, w, v0, bias, p)
 
 
 def fc_lif_scan_batched(

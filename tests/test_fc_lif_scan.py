@@ -115,18 +115,71 @@ def test_gradients_match_stbp_reference():
 
 def test_choose_fc_blocks_fits_and_raises():
     # Full-model fc1 panel at B=8 fits the default budget with block_t
-    # covering the whole Table II scan.
-    bt, bn = choose_fc_blocks(16, 8, 2048, 512, jnp.float32)
-    assert bt == 16 and bn % LANES == 0
+    # covering the whole Table II scan, all rows in one block.
+    bt, br, bn = choose_fc_blocks(16, 8, 2048, 512, jnp.float32)
+    assert bt == 16 and br == 8 and bn % LANES == 0
     # Tight budget: block_n shrinks to one lane-row before block_t drops.
-    bt2, bn2 = choose_fc_blocks(16, 8, 2048, 512, jnp.float32,
-                                vmem_budget=2 * 1024 * 1024)
+    bt2, br2, bn2 = choose_fc_blocks(16, 8, 2048, 512, jnp.float32,
+                                     vmem_budget=2 * 1024 * 1024)
     w_bytes = 4 * 2048 * bn2
     state = 2 * 4 * 8 * bn2
     per_t = 8 * (2048 * 4 + bn2 * 8)
+    assert br2 == 8
     assert w_bytes + state + bt2 * per_t <= 2 * 1024 * 1024
     with pytest.raises(ValueError, match="vmem_budget"):
         choose_fc_blocks(16, 8, 2048, 512, jnp.float32, vmem_budget=1 << 16)
+
+
+def _exact_w(key, k, n):
+    """Weights on a 2^-6 grid in [-2, 2]: every partial sum of a product
+    with small-integer inputs is exact in float32, so no summation order
+    can change a current and bitwise equality tests the kernel's
+    indexing, bias and LIF update, not the matmul's blocking."""
+    return jnp.round(_w(key, k, n, gain=4.0) * 64.0).clip(-128, 128) / 64.0
+
+
+@pytest.mark.parametrize("t,b,k,n,block_r", [
+    (4, 200, 1024, 256, 64),     # rows over four blocks, K=1024, padded
+    (3, 48, 256, 140, 16),       # N needs lane padding
+])
+def test_row_blocks_and_bias_match_unfused(t, b, k, n, block_r):
+    """With the row grid axis and a bias, the kernel equals the unfused
+    matmul + bias + lif_scan_reference bitwise, from a carried v0 too."""
+    x = jnp.floor(jax.random.uniform(jax.random.PRNGKey(b), (t, b, k))
+                  * 4.0) * _spikes(k, (t, b, k), density=0.3)
+    w = _exact_w(n, k, n)
+    bias = jnp.round(jax.random.normal(jax.random.PRNGKey(7), (n,)) * 16) / 64
+    v0 = jnp.round(jax.random.uniform(jax.random.PRNGKey(8), (b, n))
+                   * 96.0) / 64.0
+    p = LIFParams(alpha=0.5, v_th=1.0)
+    cur = jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST) + bias
+    for v in (None, v0):
+        ref_s, ref_v = lif_scan_reference(cur, p, v)
+        out_s, out_v = fc_lif_scan_pallas(x, w, p, v, bias=bias,
+                                          block_r=block_r, interpret=True)
+        np.testing.assert_array_equal(np.asarray(ref_s), np.asarray(out_s))
+        np.testing.assert_array_equal(np.asarray(ref_v), np.asarray(out_v))
+    assert 0.02 < float(ref_s.mean()) < 0.9
+
+
+def test_choose_fc_blocks_splits_token_rows():
+    """32 slots x 64 tokens at K=1024 (a Spikformer MLP's second layer)
+    no longer raises: the rows split into blocks that fit the budget."""
+    bt, br, bn = choose_fc_blocks(16, 2048, 1024, 256, jnp.float32)
+    assert br < 2048 and br % 8 == 0 and bn % LANES == 0 and bt >= 1
+    need = (4 * 1025 * bn + 2 * 4 * br * bn
+            + bt * br * (1024 * 4 + bn * 12))
+    assert need <= 8 * 1024 * 1024
+
+
+@pytest.mark.parametrize("t,b,k,n", [(16, 32, 2048, 512),
+                                     (16, 32, 512, 11)])
+def test_scnn_fc_calls_keep_one_row_block(t, b, k, n):
+    """The Table II fc1/fc2 calls at 32 slots keep all rows in one block
+    (the kernel's two-axis grid) at the blocks they always had."""
+    bt, br, bn = choose_fc_blocks(t, b, k, n, jnp.float32)
+    assert br == b
+    assert (bt, bn) == ((8, 512) if k == 2048 else (16, 128))
 
 
 def test_shape_validation():

@@ -1,6 +1,7 @@
 """Compile the served Pallas kernels for a described TPU v5e at the
 paper's Table II widths (128x128x2 input, pool4, conv16/conv32, fc
-2048 -> 512 -> 11, T=16), at B in {1, 8}.
+2048 -> 512 -> 11, T=16), at B in {1, 8}, and Spikformer-2-256's whole
+event step at 32 slots.
 
 Nothing runs: the TPU compiler lowers each kernel for a chip that is
 described, not attached, and refuses what the chip would refuse
@@ -142,6 +143,49 @@ def test_served_event_step_keeps_kernels_findable(one_chip,
     assert sorted(kernels.values()) == ["fc_lif_scan", "fc_lif_scan",
                                         "lif_scan", "lif_scan"]
     assert text.count('custom_call_target="tpu_custom_call"') == 4
+
+
+def test_spikformer_event_step_compiles_for_v5e(one_chip,
+                                               no_persistent_cache,
+                                               monkeypatch):
+    """Spikformer-2-256's event step (128x128x2 input, SPS 32/64/128/256,
+    D=256, 16 heads, L=2, T=16), as ``BatchedClosedLoop._executable``
+    compiles it at 32 slots -- 2,048 token rows, so the token linears
+    split their rows into blocks: it compiles for a described v5e, keeps
+    its module name, every ``lif_scan`` / ``fc_lif_scan`` call stays
+    findable by ``bench.lib.trace.hlo_index`` and the named scopes stay
+    in the operations' names."""
+    import importlib
+    import re
+    import sys
+
+    from repro.core import BatchedClosedLoop
+    from repro.core.spikformer import SpikformerConfig, init_spikformer
+    from repro.kernels import lif_scan
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from bench.lib import trace
+
+    for name in ("repro.kernels.fc_lif_scan", "repro.kernels.lif_scan"):
+        monkeypatch.setattr(importlib.import_module(name), "use_interpret",
+                            lambda i=None: False)
+    cfg = SpikformerConfig()
+    params = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_spikformer(jax.random.PRNGKey(0), cfg)))
+    eng = BatchedClosedLoop(params, cfg, lif_scan_fn=lif_scan, fuse_fc=True)
+    eng._zero_state_for(32)       # on the host, before the scope below
+    (device,) = one_chip.device_set
+    with jax.default_device(device):
+        text = eng._executable((32, 1024, 300_000)).as_text()
+    assert text.startswith(f"HloModule {trace.STEP_MODULE},")
+    kernels = sorted(trace.hlo_index(text)[1].values())
+    assert kernels == ["fc_lif_scan"] * 8 + ["lif_scan"] * 7
+    ops = set(re.findall(r'op_name="([^"]*)"', text))
+    scopes = ["voxelize", "sps0", "sps1", "sps2", "sps3", "rpe", "head",
+              "readout"] + [f"block{j}/{s}" for j in range(cfg.depth)
+                            for s in ("qkv", "attn", "proj", "mlp")]
+    for scope in scopes:
+        assert any(n.startswith(f"jit(run)/{scope}/") for n in ops), scope
 
 
 def test_state_move_compiles_for_v5e(one_chip, no_persistent_cache):
